@@ -254,6 +254,8 @@ def main(argv=None) -> int:
                          "every positive fault rate, the straggler gate "
                          "holds, and the clean run is a no-op")
     args = ap.parse_args(argv)
+    from benchmarks.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     if args.sizes:
         sizes = tuple(int(s) for s in args.sizes.split(","))

@@ -449,6 +449,8 @@ def main(argv=None) -> int:
                     help="fail if any sim ran below this many events/s "
                          "(0 = off)")
     args = ap.parse_args(argv)
+    from benchmarks.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     if args.sizes:
         sizes = tuple(int(s) for s in args.sizes.split(","))

@@ -257,6 +257,8 @@ def main(argv=None) -> int:
                          "simulated violations exceed predicted + this "
                          "budget (negative = off)")
     args = ap.parse_args(argv)
+    from benchmarks.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     if args.sizes:
         sizes = tuple(int(s) for s in args.sizes.split(","))

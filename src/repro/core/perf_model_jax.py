@@ -31,11 +31,13 @@ into XLA.
 
 Numerical contract: agreement with the numpy oracle is pinned at
 <= 1e-6 (tests/test_perf_model_jax.py), NOT the scalar-vs-vec 1e-9 —
-XLA may reassociate sums and fuse multiply-adds, so last-bit equality is
-out of scope by design (docs/reproduction-notes.md, deviation 5).
-Plan-level decisions still agree exactly on the pinned workloads
-because Alg. 1/2 thresholds carry 1e-9 epsilons, orders of magnitude
-above the float divergence.
+XLA may reassociate sums and fuse multiply-adds, and a TPU emulates
+float64 at ~2**-48 relative, so last-bit equality is out of scope by
+design (docs/reproduction-notes.md, deviation 5).  Plan-level decisions
+still agree exactly because Alg. 1/2 thresholds carry 1e-9 epsilons,
+orders of magnitude above the float divergence, and `alloc_all_jax`
+hands back the oracle's own allocation bits: the device decides how
+many grants, the host replays them on the oracle's grid.
 
 float64 is mandatory: the 1e-9 decision epsilons drown in float32
 noise.  Importing this module enables jax x64 mode process-wide.
@@ -178,27 +180,24 @@ def budget_ms_vec_jax(bm: BudgetModel, slo_ms, rate_rps, batch) -> np.ndarray:
 def _alloc_all_jit(hw: HardwareSpec, mask, n, ca, b, r0, budget_ms,
                    k_act0, power0, cache0, t_io, t_schk,
                    power_sum, cache_sum, d,
-                   cw, bn, r_lower, budget_new, grid):
+                   cw, bn, r_lower, budget_new):
     """One newcomer vs every open device, full Alg. 2 grant loop.
 
     Shapes are the cluster CAPACITIES; ``d`` is traced and
     ``row_valid`` masks the padding rows (they start inactive and
     infeasible-irrelevant, and the caller slices them off).  The body
-    mirrors `VecCluster.alloc_all` statement for statement; the one
-    reordering is the per-row grant delta sums (`np.subtract.at`'s
-    sequential accumulation becomes a masked row sum), covered by the
-    1e-6 contract.
+    mirrors `VecCluster.alloc_all` statement for statement, except
+    that a grant adds ``r_unit`` without the oracle's 1e-10 grid snap,
+    and the per-row grant delta sums (`np.subtract.at`'s sequential
+    accumulation) become masked row sums.  Both stay ulps away from
+    the oracle's values, far inside the 1e-9 decision epsilons.
+
+    Returns ``(feasible, g_res, g_new)``: the verdict per device and
+    how many ``+r_unit`` grants each resident and the newcomer took.
+    The caller replays those grants on the host (`alloc_all_jax`).
     """
     cap_d = mask.shape[0]
     row_valid = jnp.arange(cap_d) < d
-
-    def round_grid(x):
-        # np.round(x, 10) equivalent.  ``grid`` (1e10) is a TRACED
-        # operand on purpose: with a constant divisor XLA's fast-math
-        # rewrites ``/ 1e10`` into ``* 1e-10`` (an inexact reciprocal),
-        # and the allocations drift one ulp off the numpy oracle's grid
-        # — enough to fail bit-identical plan checks.
-        return jnp.round(x * grid) / grid
 
     def solo_new(rn):
         k_act = ((cw[_F["k1"]] * bn * bn + cw[_F["k2"]] * bn + cw[_F["k3"]])
@@ -223,7 +222,7 @@ def _alloc_all_jit(hw: HardwareSpec, mask, n, ca, b, r0, budget_ms,
         return st[-2].any()
 
     def body(st):
-        (rr, rn, ka, pw, cu, kan, pn, cn,
+        (rr, rn, g_res, g_new, ka, pw, cu, kan, pn, cn,
          p_sum, c_sum, active, feasible) = st
         tot = jnp.where(mask, rr, 0.0).sum(axis=1) + rn
         over = active & (tot > R_MAX + 1e-9)
@@ -254,7 +253,8 @@ def _alloc_all_jit(hw: HardwareSpec, mask, n, ca, b, r0, budget_ms,
 
         # grants: +r_unit to every violator on still-active devices
         grow = viol_res & act[:, None]
-        rr2 = jnp.where(grow, round_grid(rr + hw.r_unit), rr)
+        rr2 = jnp.where(grow, rr + hw.r_unit, rr)
+        g_res = g_res + grow
         k_act_g = _k_act(ca, b, rr2)
         ability_g = b / k_act_g
         p_g = ca[_F["alpha_power"]] * ability_g + ca[_F["beta_power"]]
@@ -267,25 +267,23 @@ def _alloc_all_jit(hw: HardwareSpec, mask, n, ca, b, r0, budget_ms,
         cu = jnp.where(grow, c_g, cu)
 
         grow_n = viol_new & act
-        rn2 = jnp.where(grow_n, round_grid(rn + hw.r_unit), rn)
+        rn2 = jnp.where(grow_n, rn + hw.r_unit, rn)
+        g_new = g_new + grow_n
         kan_g, pn_g, cn_g = solo_new(rn2)
         p_sum = p_sum + jnp.where(grow_n, pn_g - pn, 0.0)
         c_sum = c_sum + jnp.where(grow_n, cn_g - cn, 0.0)
         kan = jnp.where(grow_n, kan_g, kan)
         pn = jnp.where(grow_n, pn_g, pn)
         cn = jnp.where(grow_n, cn_g, cn)
-        return (rr2, rn2, ka, pw, cu, kan, pn, cn,
+        return (rr2, rn2, g_res, g_new, ka, pw, cu, kan, pn, cn,
                 p_sum, c_sum, act, feasible)
 
-    init = (r0, rn0, k_act0, power0, cache0, kan0, pn0, cn0,
-            p_sum0, c_sum0, row_valid, jnp.ones(cap_d, dtype=bool))
-    (rr, rn, _, _, _, _, _, _, _, _, _, feasible) = lax.while_loop(
-        cond, body, init)
-
-    grown = jnp.where(mask, jnp.maximum(0.0, rr - r0), 0.0)
-    r_inter = grown.sum(axis=1) + jnp.maximum(0.0, rn - r_lower)
-    r_inter = jnp.where(feasible, r_inter, jnp.inf)
-    return feasible, rr, rn, r_inter
+    init = (r0, rn0, jnp.zeros(mask.shape, jnp.int32),
+            jnp.zeros(cap_d, jnp.int32), k_act0, power0, cache0,
+            kan0, pn0, cn0, p_sum0, c_sum0, row_valid,
+            jnp.ones(cap_d, dtype=bool))
+    (_, _, g_res, g_new, *_, feasible) = lax.while_loop(cond, body, init)
+    return feasible, g_res, g_new
 
 
 def alloc_all_jax(cl: "pmv.VecCluster", spec: WorkloadSpec,
@@ -296,7 +294,11 @@ def alloc_all_jax(cl: "pmv.VecCluster", spec: WorkloadSpec,
     The per-entry ``budget_ms`` thresholds and the newcomer's budget are
     numpy-solved (cached on the cluster / `BudgetModel.budget_ms`), so
     the jitted kernel sees bit-identical decision thresholds to the
-    numpy loop.
+    numpy loop.  The kernel decides how many ``+r_unit`` grants each
+    entry takes; the allocations and the Alg. 1 score are then replayed
+    here with the oracle's own numpy statements, so they are the
+    oracle's bits whatever float64 the device has (a TPU emulates it at
+    ~2**-48 relative: every value that crossed it came back ulps off).
     """
     d = cl.d
     if d == 0:
@@ -304,11 +306,22 @@ def alloc_all_jax(cl: "pmv.VecCluster", spec: WorkloadSpec,
         return z.astype(bool), np.zeros((0, 1)), z, z
     hw = cl.hw
     budget_new = cl.bm.budget_ms(spec.slo_ms, spec.rate_rps, batch)
-    feasible, rr, rn, r_inter = _alloc_all_jit(
+    out = _alloc_all_jit(
         hw, cl.mask, cl.n, _coeff_arrays(cl.ca), cl.b, cl.r, cl.budget_ms,
         cl.k_act, cl.power, cl.cache, cl.t_io, cl.t_schk,
         cl.power_sum, cl.cache_sum, np.int64(d),
         _coeff_scalars(coeffs), np.float64(batch), np.float64(r_lower),
-        np.float64(budget_new), np.float64(1e10))
-    return (np.asarray(feasible)[:d], np.asarray(rr)[:d],
-            np.asarray(rn)[:d], np.asarray(r_inter)[:d])
+        np.float64(budget_new))
+    feasible, g_res, g_new = (np.asarray(a)[:d] for a in out)
+    r0 = cl.r[:d]
+    rr = r0.copy()
+    for i in range(int(g_res.max(initial=0))):
+        rr = np.where(g_res > i, np.round(rr + hw.r_unit, 10), rr)
+    rn = np.full(d, r_lower)
+    for i in range(int(g_new.max(initial=0))):
+        rn = np.where(g_new > i, np.round(rn + hw.r_unit, 10), rn)
+    # Alg. 1 line 8, as `VecCluster.alloc_all` computes it
+    grown = np.where(cl.mask[:d], np.maximum(0.0, rr - r0), 0.0)
+    r_inter = grown.sum(axis=1) + np.maximum(0.0, rn - r_lower)
+    r_inter = np.where(feasible, r_inter, np.inf)
+    return feasible, rr, rn, r_inter

@@ -324,8 +324,8 @@ def update_kv_cache(cache: KVCache, k_new, v_new):
 
     def write(bufarr, new):
         # new: (B, 1, KV, hd) -> heads-major (B, KV, 1, hd)
-        return jax.lax.dynamic_update_slice(
-            bufarr, new.swapaxes(1, 2).astype(bufarr.dtype), (0, 0, idx, 0))
+        return jax.lax.dynamic_update_slice_in_dim(
+            bufarr, new.swapaxes(1, 2).astype(bufarr.dtype), idx, axis=2)
 
     return cache._replace(k=write(cache.k, k_new),
                           v=write(cache.v, v_new),
