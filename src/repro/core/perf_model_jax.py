@@ -58,6 +58,7 @@ from jax import lax  # noqa: E402
 
 from repro.core import perf_model as pm  # noqa: E402
 from repro.core import perf_model_vec as pmv  # noqa: E402
+from repro.core import trace  # noqa: E402
 from repro.core.queueing import (  # noqa: E402
     RHO_MAX, SOLVE_ITERS, BudgetModel)
 from repro.core.types import (  # noqa: E402
@@ -192,8 +193,9 @@ def _alloc_all_jit(hw: HardwareSpec, mask, n, ca, b, r0, budget_ms,
     accumulation) become masked row sums.  Both stay ulps away from
     the oracle's values, far inside the 1e-9 decision epsilons.
 
-    Returns ``(feasible, g_res, g_new)``: the verdict per device and
-    how many ``+r_unit`` grants each resident and the newcomer took.
+    Returns ``(feasible, g_res, g_new, iters)``: the verdict per
+    device, how many ``+r_unit`` grants each resident and the newcomer
+    took, and the number of loop iterations (the numpy loop's count).
     The caller replays those grants on the host (`alloc_all_jax`).
     """
     cap_d = mask.shape[0]
@@ -219,11 +221,11 @@ def _alloc_all_jit(hw: HardwareSpec, mask, n, ca, b, r0, budget_ms,
     t_schk_new = cw[_F["k_sch"]] * cw[_F["n_kernels"]]
 
     def cond(st):
-        return st[-2].any()
+        return st[-3].any()                                   # active
 
     def body(st):
         (rr, rn, g_res, g_new, ka, pw, cu, kan, pn, cn,
-         p_sum, c_sum, active, feasible) = st
+         p_sum, c_sum, active, feasible, it) = st
         tot = jnp.where(mask, rr, 0.0).sum(axis=1) + rn
         over = active & (tot > R_MAX + 1e-9)
         feasible = feasible & ~over
@@ -276,14 +278,15 @@ def _alloc_all_jit(hw: HardwareSpec, mask, n, ca, b, r0, budget_ms,
         pn = jnp.where(grow_n, pn_g, pn)
         cn = jnp.where(grow_n, cn_g, cn)
         return (rr2, rn2, g_res, g_new, ka, pw, cu, kan, pn, cn,
-                p_sum, c_sum, act, feasible)
+                p_sum, c_sum, act, feasible, it + 1)
 
     init = (r0, rn0, jnp.zeros(mask.shape, jnp.int32),
             jnp.zeros(cap_d, jnp.int32), k_act0, power0, cache0,
             kan0, pn0, cn0, p_sum0, c_sum0, row_valid,
-            jnp.ones(cap_d, dtype=bool))
-    (_, _, g_res, g_new, *_, feasible) = lax.while_loop(cond, body, init)
-    return feasible, g_res, g_new
+            jnp.ones(cap_d, dtype=bool), jnp.int32(0))
+    (_, _, g_res, g_new, *_, feasible, iters) = lax.while_loop(cond, body,
+                                                               init)
+    return feasible, g_res, g_new, iters
 
 
 def alloc_all_jax(cl: "pmv.VecCluster", spec: WorkloadSpec,
@@ -299,29 +302,41 @@ def alloc_all_jax(cl: "pmv.VecCluster", spec: WorkloadSpec,
     here with the oracle's own numpy statements, so they are the
     oracle's bits whatever float64 the device has (a TPU emulates it at
     ~2**-48 relative: every value that crossed it came back ulps off).
+
+    While a profiler trace records, the loop's iteration count is
+    fetched too, into ``cl.iters``, its copy started before the fetch so
+    that it overlaps it; otherwise it stays on the device.
     """
     d = cl.d
     if d == 0:
         z = np.zeros(0)
         return z.astype(bool), np.zeros((0, 1)), z, z
     hw = cl.hw
-    budget_new = cl.bm.budget_ms(spec.slo_ms, spec.rate_rps, batch)
-    out = _alloc_all_jit(
-        hw, cl.mask, cl.n, _coeff_arrays(cl.ca), cl.b, cl.r, cl.budget_ms,
-        cl.k_act, cl.power, cl.cache, cl.t_io, cl.t_schk,
-        cl.power_sum, cl.cache_sum, np.int64(d),
-        _coeff_scalars(coeffs), np.float64(batch), np.float64(r_lower),
-        np.float64(budget_new))
-    feasible, g_res, g_new = (np.asarray(a)[:d] for a in out)
-    r0 = cl.r[:d]
-    rr = r0.copy()
-    for i in range(int(g_res.max(initial=0))):
-        rr = np.where(g_res > i, np.round(rr + hw.r_unit, 10), rr)
-    rn = np.full(d, r_lower)
-    for i in range(int(g_new.max(initial=0))):
-        rn = np.where(g_new > i, np.round(rn + hw.r_unit, 10), rn)
-    # Alg. 1 line 8, as `VecCluster.alloc_all` computes it
-    grown = np.where(cl.mask[:d], np.maximum(0.0, rr - r0), 0.0)
-    r_inter = grown.sum(axis=1) + np.maximum(0.0, rn - r_lower)
-    r_inter = np.where(feasible, r_inter, np.inf)
+    with trace.span("alloc_all.launch"):
+        budget_new = cl.bm.budget_ms(spec.slo_ms, spec.rate_rps, batch)
+        out = _alloc_all_jit(
+            hw, cl.mask, cl.n, _coeff_arrays(cl.ca), cl.b, cl.r,
+            cl.budget_ms, cl.k_act, cl.power, cl.cache, cl.t_io, cl.t_schk,
+            cl.power_sum, cl.cache_sum, np.int64(d),
+            _coeff_scalars(coeffs), np.float64(batch), np.float64(r_lower),
+            np.float64(budget_new))
+    counting = trace.active()
+    if counting:
+        out[3].copy_to_host_async()
+    with trace.span("alloc_all.fetch"):
+        feasible, g_res, g_new = (np.asarray(a)[:d] for a in out[:3])
+    if counting:
+        cl.iters = int(out[3])
+    with trace.span("alloc_all.replay"):
+        r0 = cl.r[:d]
+        rr = r0.copy()
+        for i in range(int(g_res.max(initial=0))):
+            rr = np.where(g_res > i, np.round(rr + hw.r_unit, 10), rr)
+        rn = np.full(d, r_lower)
+        for i in range(int(g_new.max(initial=0))):
+            rn = np.where(g_new > i, np.round(rn + hw.r_unit, 10), rn)
+        # Alg. 1 line 8, as `VecCluster.alloc_all` computes it
+        grown = np.where(cl.mask[:d], np.maximum(0.0, rr - r0), 0.0)
+        r_inter = grown.sum(axis=1) + np.maximum(0.0, rn - r_lower)
+        r_inter = np.where(feasible, r_inter, np.inf)
     return feasible, rr, rn, r_inter

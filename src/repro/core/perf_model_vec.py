@@ -40,6 +40,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core import perf_model as pm
+from repro.core import trace
 from repro.core.queueing import BudgetLike, QUEUEING, resolve
 from repro.core.types import HardwareSpec, WorkloadCoefficients, WorkloadSpec
 
@@ -233,6 +234,9 @@ class VecCluster:
         self.t_schk = np.zeros((cap_d, cap_n))      # k_sch * n_kernels
         self.power_sum = np.zeros(cap_d)
         self.cache_sum = np.zeros(cap_d)
+        # Alg. 2 loop iterations of the last `alloc_all` call; the jax
+        # backend fetches the count only while a profiler trace records
+        self.iters: Optional[int] = None
 
     # -- capacity management ------------------------------------------------
 
@@ -422,16 +426,34 @@ class VecCluster:
         With ``backend="jax"`` the loop runs as the jitted
         `perf_model_jax.alloc_all_jax` twin instead (<= 1e-6 agreement;
         identical plans on the pinned workloads).
+
+        The call is the profiler span ``igniter.alloc_all``, which
+        carries the loop's iteration count ``iters`` while a trace
+        records.
         """
+        with trace.span("alloc_all") as sp:
+            self.iters = None
+            if self.d == 0:
+                z = np.zeros(0)
+                return z.astype(bool), np.zeros((0, 1)), z, z
+            if self.backend == "jax":
+                from repro.core import perf_model_jax
+                out = perf_model_jax.alloc_all_jax(self, spec, coeffs,
+                                                   batch, r_lower)
+            else:
+                out = self._alloc_all_numpy(spec, coeffs, batch, r_lower)
+            if self.iters is not None and trace.active():
+                sp.set(iters=self.iters)
+            return out
+
+    def _alloc_all_numpy(self, spec: WorkloadSpec,
+                         coeffs: WorkloadCoefficients, batch: int,
+                         r_lower: float
+                         ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
+                                    np.ndarray]:
+        """`alloc_all`'s numpy loop (the oracle); counts ``self.iters``."""
         hw = self.hw
         d = self.d
-        if d == 0:
-            z = np.zeros(0)
-            return z.astype(bool), np.zeros((0, 1)), z, z
-        if self.backend == "jax":
-            from repro.core import perf_model_jax
-            return perf_model_jax.alloc_all_jax(self, spec, coeffs,
-                                                batch, r_lower)
         ncap = self.mask.shape[1]
         mask = self.mask[:d]
 
@@ -470,7 +492,9 @@ class VecCluster:
 
         active = np.ones(d, dtype=bool)
         feasible = np.ones(d, dtype=bool)
-        while True:
+        iters = 0
+        while active.any():
+            iters += 1
             # loop-top capacity check (scalar: `while sum(r_a) <= R_MAX`)
             tot = np.where(mask, rr, 0.0).sum(axis=1) + rn
             over = active & (tot > R_MAX + 1e-9)
@@ -537,6 +561,7 @@ class VecCluster:
         grown = np.where(mask, np.maximum(0.0, rr - self.r[:d]), 0.0)
         r_inter = grown.sum(axis=1) + np.maximum(0.0, rn - r_lower)
         r_inter = np.where(feasible, r_inter, np.inf)
+        self.iters = iters
         return feasible, rr, rn, r_inter
 
 

@@ -28,6 +28,7 @@ import numpy as np
 from repro.core import perf_model as pm
 from repro.core import perf_model_vec as pmv
 from repro.core import replication
+from repro.core import trace
 from repro.core.queueing import BudgetLike, BudgetModel, QUEUEING, resolve
 from repro.core.types import (HardwareSpec, K_MAX, Placement, PlannerConfig,
                               ProvisioningPlan, WorkloadCoefficients,
@@ -450,6 +451,7 @@ def _check_device_cap(used: int, max_devices: Optional[int], name: str,
         raise DeviceCapError(msg, per_hw={hw.name: msg})
 
 
+@trace.spanned("provision")
 def provision(specs: Sequence[WorkloadSpec],
               profiles: Dict[str, WorkloadCoefficients],
               hw: HardwareSpec, *,
@@ -557,22 +559,26 @@ def _provision_vec(specs: Sequence[WorkloadSpec],
     scores every open device per placement, and the chosen device's
     invariants are refreshed incrementally."""
     bm = resolve(cfg.budget)
-    prepared = _prepare(specs, profiles, hw, budget=bm, batch=cfg.batch,
-                        replicate=cfg.replicate, k_max=cfg.k_max)
+    with trace.span("prepare"):
+        prepared = _prepare(specs, profiles, hw, budget=bm, batch=cfg.batch,
+                            replicate=cfg.replicate, k_max=cfg.k_max)
 
     cl = pmv.VecCluster(hw, budget=bm, backend=cfg.backend)
     cl.add_device()
     for (s, c, b, rl) in prepared:
         feasible, rr, rn, r_inter = cl.alloc_all(s, c, b, rl)
-        best_q = _argmin_inter(r_inter) if feasible.any() else -1
-        if best_q == -1:
-            _check_device_cap(sum(1 for g in range(cl.d) if cl.entries[g]),
-                              max_devices, s.name, hw)
-            q = cl.add_device()                                  # line 14
-            cl.add_entry(q, s, c, b, self_grant(s, c, b, rl, hw, budget=bm))
-        else:
-            cl.set_row_r(best_q, rr[best_q])
-            cl.add_entry(best_q, s, c, b, float(rn[best_q]))
+        with trace.span("place"):                              # lines 8-14
+            best_q = _argmin_inter(r_inter) if feasible.any() else -1
+            if best_q == -1:
+                _check_device_cap(sum(1 for g in range(cl.d)
+                                      if cl.entries[g]),
+                                  max_devices, s.name, hw)
+                q = cl.add_device()                              # line 14
+                cl.add_entry(q, s, c, b,
+                             self_grant(s, c, b, rl, hw, budget=bm))
+            else:
+                cl.set_row_r(best_q, rr[best_q])
+                cl.add_entry(best_q, s, c, b, float(rn[best_q]))
 
     plan = ProvisioningPlan(hardware=hw)
     for g in range(cl.d):
@@ -626,6 +632,7 @@ def _rebalance_replica_shares(plan: ProvisioningPlan,
 # workloads on the chosen device to absorb the newcomer's interference.
 # ---------------------------------------------------------------------------
 
+@trace.spanned("add_workload")
 def add_workload(plan: ProvisioningPlan, spec: WorkloadSpec,
                  profiles: Dict[str, WorkloadCoefficients],
                  hw: HardwareSpec, *,
@@ -680,42 +687,48 @@ def add_workload(plan: ProvisioningPlan, spec: WorkloadSpec,
         b, rl = int(pin[0]), float(pin[1])
     else:
         try:
-            b = appropriate_batch(spec, c, hw, budget=bm, batch=cfg.batch)
-            rl = resource_lower_bound(spec, c, hw, b, budget=bm)
+            with trace.span("prepare"):
+                b = appropriate_batch(spec, c, hw, budget=bm,
+                                      batch=cfg.batch)
+                rl = resource_lower_bound(spec, c, hw, b, budget=bm)
         except InfeasibleError as e:
             if not e.per_hw:
                 e.per_hw = {hw.name: str(e)}
             raise
 
-    devs: Dict[int, _Dev] = {}
-    for p in plan.placements:
-        devs.setdefault(p.gpu, _Dev()).entries.append(
-            (p.workload, profiles[p.workload.model], p.batch, p.r))
-    cand = devs if not exclude_gpus else \
-        {g: d for g, d in devs.items() if g not in exclude_gpus}
+    with trace.span("cluster_build"):
+        devs: Dict[int, _Dev] = {}
+        for p in plan.placements:
+            devs.setdefault(p.gpu, _Dev()).entries.append(
+                (p.workload, profiles[p.workload.model], p.batch, p.r))
+        cand = devs if not exclude_gpus else \
+            {g: d for g, d in devs.items() if g not in exclude_gpus}
+        if cfg.engine == "vec":
+            cl = pmv.VecCluster(hw, budget=bm, backend=cfg.backend)
+            gpu_ids = sorted(cand)
+            for g in gpu_ids:
+                q = cl.add_device()
+                for (s, cc, bb, r) in cand[g].entries:
+                    cl.add_entry(q, s, cc, bb, r)
 
     best_q, best_alloc, best_inter = -1, None, R_MAX + 1.0
     if cfg.engine == "vec":
-        cl = pmv.VecCluster(hw, budget=bm, backend=cfg.backend)
-        gpu_ids = sorted(cand)
-        for g in gpu_ids:
-            q = cl.add_device()
-            for (s, cc, bb, r) in cand[g].entries:
-                cl.add_entry(q, s, cc, bb, r)
         if gpu_ids:
             feasible, rr, rn, r_inter = cl.alloc_all(spec, c, b, rl)
-            if reserved:
-                resv = np.array([reserved.get(g, 0.0) for g in gpu_ids])
-                if resv.any():
-                    load = (rr * cl.mask[:cl.d]).sum(axis=1) + rn + resv
-                    over = load > 1.0 + 1e-9
-                    feasible = feasible & ~over
-                    r_inter = np.where(over, np.inf, r_inter)
-            row = _argmin_inter(r_inter) if feasible.any() else -1
-            if row != -1:
-                best_q = gpu_ids[row]
-                k = int(cl.n[row])
-                best_alloc = [float(x) for x in rr[row, :k]] + [float(rn[row])]
+            with trace.span("place"):
+                if reserved:
+                    resv = np.array([reserved.get(g, 0.0) for g in gpu_ids])
+                    if resv.any():
+                        load = (rr * cl.mask[:cl.d]).sum(axis=1) + rn + resv
+                        over = load > 1.0 + 1e-9
+                        feasible = feasible & ~over
+                        r_inter = np.where(over, np.inf, r_inter)
+                row = _argmin_inter(r_inter) if feasible.any() else -1
+                if row != -1:
+                    best_q = gpu_ids[row]
+                    k = int(cl.n[row])
+                    best_alloc = ([float(x) for x in rr[row, :k]]
+                                  + [float(rn[row])])
     else:
         for q, dev in sorted(cand.items()):
             r_a = alloc_gpus(dev, spec, c, b, rl, hw, budget=bm)
@@ -759,6 +772,7 @@ def add_workload(plan: ProvisioningPlan, spec: WorkloadSpec,
 # has a scalar-oracle twin pinned by tests.
 # ---------------------------------------------------------------------------
 
+@trace.spanned("remove_workload")
 def remove_workload(plan: ProvisioningPlan, name: str, *,
                     telemetry=None) -> ProvisioningPlan:
     """Drop one workload's placement (departure).  Remaining residents
@@ -776,6 +790,7 @@ def remove_workload(plan: ProvisioningPlan, name: str, *,
     return new_plan
 
 
+@trace.spanned("resize_workload")
 def resize_workload(plan: ProvisioningPlan, spec: WorkloadSpec,
                     profiles: Dict[str, WorkloadCoefficients],
                     hw: HardwareSpec, *,

@@ -60,7 +60,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time as _time
 import warnings
 from collections import deque
 from dataclasses import dataclass
@@ -72,6 +71,7 @@ from repro.core import perf_model as pm
 from repro.core import perf_model_vec as pmv
 from repro.core import provisioner as prov
 from repro.core import replication
+from repro.core import trace
 from repro.core.queueing import BudgetLike, QUEUEING, resolve
 from repro.core.types import (HardwareSpec, Placement, PlannerConfig,
                               ProvisioningPlan, WorkloadCoefficients,
@@ -1974,76 +1974,71 @@ class Controller:
                         inst.spec.name, float(inst.shadow_r))
         window_ms = max((now_s - self._last_s) * 1000.0, 1e-9)
         tm = self.telemetry
+        # Sec. 5.5-style phases, each a profiler span whose wall also
+        # goes to the telemetry walls: probe = estimator + health
+        # observation, solve = plan reconciliation, apply = mapping the
+        # plan onto live instances
+        with trace.span("ctl.probe", tm, "ctl_probe"):
+            if tm is not None:
+                # pre-edit placement snapshot + stream cursors, so every
+                # decision this tick drains into an enriched ControlEvent
+                n_edits0 = len(self.reconciler.edits)
+                n_adm0 = len(self.reconciler.admission_log)
+                pre_map: Dict[str, List[tuple]] = {}
+                for p in self.plan.placements:
+                    pre_map.setdefault(
+                        replication.base_name(p.workload.name),
+                        []).append((p.gpu, p.batch, p.r))
+            backlog: Dict[str, float] = {}
+            by_base: Dict[str, List[ServedInstance]] = {}
+            for inst in instances:
+                by_base.setdefault(replication.base_name(inst.spec.name),
+                                   []).append(inst)
+            for base, insts_b in by_base.items():
+                est = self.estimators.get(base)
+                if est is None:       # instance outside the managed plan
+                    continue
+                if len(insts_b) == 1:
+                    merged = insts_b[0].recent_arrivals
+                else:
+                    # replica slices partition the pooled stream; their
+                    # sorted merge is the workload's arrival window
+                    merged = np.sort(np.concatenate(
+                        [np.asarray(i.recent_arrivals) for i in insts_b]))
+                est.observe(merged, window_ms)
+                backlog[base] = float(sum(len(i.queue) for i in insts_b))
+            # bases holding an ACTIVE shadow: the predictive tier's
+            # disarm hold waits for these to deactivate before releasing
+            # capacity
+            self.reconciler.shadow_active_bases = {
+                base for base, insts_b in by_base.items()
+                if any(i.shadow_active for i in insts_b)}
+            changed = False
+            rep = None
+            if self.health is not None:
+                rep = self.health.observe(now_s, instances,
+                                          canary=self._canary)
+        with trace.span("ctl.solve", tm, "ctl_solve") as solve:
+            if rep is not None:
+                if rep.readmit:
+                    for g in rep.readmit:
+                        self.health.quarantined.pop(g, None)
+                    self.reconciler.readmit(now_s, rep.readmit)
+                for g in rep.dead:
+                    self.health.quarantined[g] = ("failed", now_s)
+                for g in rep.stragglers:
+                    self.health.quarantined[g] = ("straggler", now_s)
+                if rep.dead or rep.stragglers:
+                    self.reconciler.quarantine(rep.dead + rep.stragglers)
+                    changed |= self.reconciler.evict(now_s)
+            changed |= self.reconciler.reconcile(now_s, self.estimators,
+                                                 backlog, window_ms)
+        with trace.span("ctl.apply", tm, "ctl_apply"):
+            if changed:
+                self._apply_plan(instances)
         if tm is not None:
-            # pre-edit placement snapshot + stream cursors, so every
-            # decision this tick drains into an enriched ControlEvent
-            t0 = _time.perf_counter()
-            n_edits0 = len(self.reconciler.edits)
-            n_adm0 = len(self.reconciler.admission_log)
-            pre_map: Dict[str, List[tuple]] = {}
-            for p in self.plan.placements:
-                pre_map.setdefault(
-                    replication.base_name(p.workload.name),
-                    []).append((p.gpu, p.batch, p.r))
-        backlog: Dict[str, float] = {}
-        by_base: Dict[str, List[ServedInstance]] = {}
-        for inst in instances:
-            by_base.setdefault(replication.base_name(inst.spec.name),
-                               []).append(inst)
-        for base, insts_b in by_base.items():
-            est = self.estimators.get(base)
-            if est is None:       # instance outside the managed plan
-                continue
-            if len(insts_b) == 1:
-                merged = insts_b[0].recent_arrivals
-            else:
-                # replica slices partition the pooled stream; their
-                # sorted merge is the workload's arrival window
-                merged = np.sort(np.concatenate(
-                    [np.asarray(i.recent_arrivals) for i in insts_b]))
-            est.observe(merged, window_ms)
-            backlog[base] = float(sum(len(i.queue) for i in insts_b))
-        # bases holding an ACTIVE shadow: the predictive tier's disarm
-        # hold waits for these to deactivate before releasing capacity
-        self.reconciler.shadow_active_bases = {
-            base for base, insts_b in by_base.items()
-            if any(i.shadow_active for i in insts_b)}
-        changed = False
-        rep = None
-        if self.health is not None:
-            rep = self.health.observe(now_s, instances,
-                                      canary=self._canary)
-        if tm is not None:
-            # Sec. 5.5-style phase walls: probe = estimator + health
-            # observation, solve = plan reconciliation, apply = mapping
-            # the plan onto live instances
-            t1 = _time.perf_counter()
-            tm.add_wall("ctl_probe", (t1 - t0) * 1000.0)
-        if rep is not None:
-            if rep.readmit:
-                for g in rep.readmit:
-                    self.health.quarantined.pop(g, None)
-                self.reconciler.readmit(now_s, rep.readmit)
-            for g in rep.dead:
-                self.health.quarantined[g] = ("failed", now_s)
-            for g in rep.stragglers:
-                self.health.quarantined[g] = ("straggler", now_s)
-            if rep.dead or rep.stragglers:
-                self.reconciler.quarantine(rep.dead + rep.stragglers)
-                changed |= self.reconciler.evict(now_s)
-        changed |= self.reconciler.reconcile(now_s, self.estimators,
-                                             backlog, window_ms)
-        solve_ms = 0.0
-        if tm is not None:
-            t2 = _time.perf_counter()
-            solve_ms = (t2 - t1) * 1000.0
-            tm.add_wall("ctl_solve", solve_ms)
-        if changed:
-            self._apply_plan(instances)
-        if tm is not None:
-            tm.add_wall("ctl_apply", (_time.perf_counter() - t2) * 1000.0)
             self._drain_events(now_s, rep, pre_map, n_edits0, n_adm0,
-                               solve_ms)
+                               solve.ms)
             tm.gauge("probe_hits", self.reconciler.probes.hits)
             tm.gauge("probe_misses", self.reconciler.probes.misses)
         self._last_s = now_s

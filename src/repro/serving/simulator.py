@@ -84,6 +84,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core import replication
+from repro.core.trace import span, spanned
 from repro.core.coefficients import ProfileSample
 from repro.core.types import HardwareSpec, ProvisioningPlan, WorkloadSpec
 from repro.profiling.metrics import ServedModelDesc
@@ -428,6 +429,7 @@ def _resync_replicas(router: _ReplicaRouter,
     return changed
 
 
+@spanned("sim.setup")
 def _setup(plan: ProvisioningPlan, models: Dict[str, ServedModelDesc],
            shadow: bool, shadow_extra: float, horizon_ms: float,
            poisson: bool, seed: int,
@@ -525,28 +527,29 @@ def _snap_placement(inst: ServedInstance):
 
 
 def _call_adjust(adjust_fn: AdjustFn, now_s: float,
-                 insts: List[ServedInstance]
+                 insts: List[ServedInstance], telemetry=None
                  ) -> Tuple[List[Tuple[ServedInstance, int]],
                             List[ServedInstance], float]:
     """Invoke the callback; return ([(changed_inst, old_gpu)],
     [appended new instances], wall_ms).  A "reconfiguration" is any
     change to an instance's placement tuple (gpu, r, batch, shadow_r,
     shadow_active); a scale-out callback may APPEND fresh
-    `ServedInstance`s (replica scale-out) to the list it was handed."""
+    `ServedInstance`s (replica scale-out) to the list it was handed.
+    The call is the span ``igniter.sim.adjust``, whose wall also goes
+    to ``telemetry``'s ``sim_adjust``."""
     n0 = len(insts)
     snaps = [_snap_placement(i) for i in insts]
-    t0 = _time.perf_counter()
-    adjust_fn(now_s, insts)
-    wall_ms = (_time.perf_counter() - t0) * 1000.0
+    with span("sim.adjust", telemetry, "sim_adjust") as sp:
+        adjust_fn(now_s, insts)
     changed = [(inst, s[0]) for inst, s in zip(insts[:n0], snaps)
                if _snap_placement(inst) != s]
-    return changed, list(insts[n0:]), wall_ms
+    return changed, list(insts[n0:]), sp.ms
 
 
 def _dispatch_adjust(adjust_fn: AdjustFn, now_s: float,
                      instances: List[ServedInstance],
-                     by_gpu: Dict[int, List[int]], adjust_scope: str
-                     ) -> Tuple[List[Tuple[ServedInstance, int]],
+                     by_gpu: Dict[int, List[int]], adjust_scope: str,
+                     telemetry=None) -> Tuple[List[Tuple[ServedInstance, int]],
                                 List[ServedInstance], float]:
     """Scope-aware adjust_fn dispatch, shared by BOTH engines so the
     call grouping/ordering that the byte-identical contract depends on
@@ -563,7 +566,8 @@ def _dispatch_adjust(adjust_fn: AdjustFn, now_s: float,
     new_all: List[ServedInstance] = []
     wall_ms = 0.0
     for insts_c in calls:
-        changed, new, dt = _call_adjust(adjust_fn, now_s, insts_c)
+        changed, new, dt = _call_adjust(adjust_fn, now_s, insts_c,
+                                        telemetry)
         if new and adjust_scope != "cluster":
             raise RuntimeError(
                 "adjust_fn appended instances under adjust_scope="
@@ -766,6 +770,7 @@ class _FaultState:
         }
 
 
+@spanned("sim.finalize")
 def _finalize(instances: List[ServedInstance], duration_s: float,
               timeline: List[Dict], stats: Dict[str, float]) -> SimResult:
     per = {}
@@ -1034,12 +1039,12 @@ def _simulate_scalar(plan, models, hw, *, duration_s, seed, poisson, shadow,
             _sync_recent_arrivals(instances, arrivals, now, adj_window_ms)
             n_before = len(instances)
             changed, new, wall_ms = _dispatch_adjust(
-                adjust_fn, now / 1000.0, instances, by_gpu, adjust_scope)
+                adjust_fn, now / 1000.0, instances, by_gpu, adjust_scope,
+                telemetry)
             n_reconfigs += len(changed) + len(new)
             adjust_wall_ms += wall_ms
             if telemetry is not None:
                 _emit_reconfigs(telemetry, now, changed, new, wall_ms)
-                telemetry.add_wall("sim_adjust", wall_ms)
             for j in range(n_before, len(instances)):
                 # appended replica: fresh per-instance RNG streams keyed
                 # by its (new, never-reused) global index — the vec
@@ -1156,6 +1161,7 @@ class _LatTable:
 _BULK_CHUNK = 1 << 19    # max rows*n per bulk physics call (~50 MB live)
 
 
+@spanned("sim.tables")
 def _build_tables_bulk(instances: List[ServedInstance],
                        groups: Dict[int, List[int]], hw: HardwareSpec,
                        backend: str = "numpy") -> Dict[int, "_LatTable"]:
@@ -1248,6 +1254,7 @@ def _build_tables_chunk(instances: List[ServedInstance],
             slow[sl].tolist())
 
 
+@spanned("simulate")
 def _simulate_vec(plan, models, hw, *, duration_s, seed, poisson, shadow,
                   shadow_extra, monitor_period_s, adjust_fn,
                   adjust_period_s, record_timeline, adjust_scope,
@@ -1404,8 +1411,9 @@ def _simulate_vec(plan, models, hw, *, duration_s, seed, poisson, shadow,
         completed[i] = jj - inst_i.shed_count   # all served so far
 
     for (T, is_mon, is_adj) in epochs:
-        for i in range(n_inst):
-            run_passes(i, T)
+        with span("sim.passes"):
+            for i in range(n_inst):
+                run_passes(i, T)
         dirty: set = set()             # device ids needing table rebuilds
         if is_mon:
             cutoff = T - MONITOR_WINDOW_MS
@@ -1462,12 +1470,12 @@ def _simulate_vec(plan, models, hw, *, duration_s, seed, poisson, shadow,
             _sync_recent_arrivals(instances, arr_np, T, adj_window_ms)
             n_before = n_inst
             changed, new, wall_ms = _dispatch_adjust(
-                adjust_fn, T / 1000.0, instances, by_gpu, adjust_scope)
+                adjust_fn, T / 1000.0, instances, by_gpu, adjust_scope,
+                telemetry)
             n_reconfigs += len(changed) + len(new)
             adjust_wall_ms += wall_ms
             if telemetry is not None:
                 _emit_reconfigs(telemetry, T, changed, new, wall_ms)
-                telemetry.add_wall("sim_adjust", wall_ms)
             for j in range(n_before, len(instances)):
                 # appended replica: same RNG keys as the scalar oracle
                 noise_a.append(_NoiseStream(

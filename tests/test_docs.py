@@ -39,6 +39,7 @@ MODULES = {
     "controller": "src/repro/serving/controller.py",
     "workload": "src/repro/serving/workload.py",
     "telemetry": "src/repro/serving/telemetry.py",
+    "trace": "src/repro/core/trace.py",
     "telemetry_report": "benchmarks/telemetry_report.py",
 }
 
