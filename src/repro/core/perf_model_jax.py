@@ -29,6 +29,16 @@ Per-entry SLO budgets are always solved on the numpy side
 bit-identical thresholds, and only the model arithmetic itself crosses
 into XLA.
 
+Residency: the grant loop reads the cluster from one packed float64
+array of shape (cap_d, W), one record per device row (`pack`), which
+stays on the device between calls as ``VecCluster.mirror``.  Each call
+`sync`s it first: the rows the cluster marked dirty since the last call
+go as one (K_ROWS, W + 1) block, scattered into the donated mirror; a
+first call, a capacity change or more than K_ROWS dirty rows copy the
+whole state instead.  The newcomer travels as one float64 vector.  So
+a call of Alg. 1's provision loop, which changes one device row between
+calls, copies two small arrays to the device, not the whole cluster.
+
 Numerical contract: agreement with the numpy oracle is pinned at
 <= 1e-6 (tests/test_perf_model_jax.py), NOT the scalar-vs-vec 1e-9 —
 XLA may reassociate sums and fuse multiply-adds, and a TPU emulates
@@ -69,6 +79,16 @@ R_MAX = pmv.R_MAX
 # Index layout of the flat coefficient tuples handed to jitted kernels
 # (same order as perf_model_vec.COEFF_FIELDS).
 _F = {name: i for i, name in enumerate(pmv.COEFF_FIELDS)}
+
+
+# The packed record of one device row: each per-slot field takes cap_n
+# columns, in this order, then each per-row field one column.
+SLOT_FIELDS = pmv.COEFF_FIELDS + (
+    "b", "r", "budget_ms", "mask", "k_act", "power", "cache",
+    "t_load", "t_feedback", "t_schk")
+ROW_FIELDS = ("power_sum", "cache_sum", "n")
+# Dirty rows sent as one block; with more, the whole state is copied.
+K_ROWS = 8
 
 
 def _coeff_scalars(c: WorkloadCoefficients) -> Tuple[float, ...]:
@@ -177,12 +197,67 @@ def budget_ms_vec_jax(bm: BudgetModel, slo_ms, rate_rps, batch) -> np.ndarray:
 # Algorithm 2 over every open device: lax.while_loop
 # ---------------------------------------------------------------------------
 
+def pack(cl: "pmv.VecCluster", rows=slice(None)) -> np.ndarray:
+    """The packed float64 records of ``cl``'s device rows ``rows``
+    (all by default), shape (rows, W): the layout `_unpack` reads."""
+    return np.concatenate(
+        [getattr(cl.ca, f)[rows] for f in pmv.COEFF_FIELDS]
+        + [cl.b[rows], cl.r[rows], cl.budget_ms[rows], cl.mask[rows],
+           cl.k_act[rows], cl.power[rows], cl.cache[rows],
+           cl.t_io[rows, :, 0], cl.t_io[rows, :, 1], cl.t_schk[rows],
+           cl.power_sum[rows, None], cl.cache_sum[rows, None],
+           cl.n[rows, None]], axis=1, dtype=np.float64)
+
+
+def _unpack(state):
+    """`pack`'s fields, by name, sliced out of the (cap_d, W) state."""
+    cap_n = (state.shape[1] - len(ROW_FIELDS)) // len(SLOT_FIELDS)
+    f = {name: state[:, i * cap_n:(i + 1) * cap_n]
+         for i, name in enumerate(SLOT_FIELDS)}
+    f.update((name, state[:, i - len(ROW_FIELDS)])
+             for i, name in enumerate(ROW_FIELDS))
+    return f
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _scatter_rows_jit(state, block):
+    """The donated device state with ``block``'s records written in: the
+    last column of the block holds each record's row; padding records
+    hold cap_d, out of bounds, and are dropped."""
+    rows = block[:, -1].astype(jnp.int32)
+    return state.at[rows].set(block[:, :-1], mode="drop")
+
+
+def sync(cl: "pmv.VecCluster") -> int:
+    """Bring ``cl.mirror``, the device copy of ``pack(cl)``, up to date
+    and clear ``cl.dirty``; returns the rows copied (cap_d for the whole
+    state).  `VecCluster._grow` drops the mirror when a capacity
+    changes, so a mirror found here has the state's shape."""
+    dirty = np.flatnonzero(cl.dirty)
+    cap_d = cl.mask.shape[0]
+    if cl.mirror is None or dirty.size > K_ROWS:
+        cl.mirror = jax.device_put(pack(cl))
+        sent = cap_d
+    elif dirty.size:
+        block = np.full((K_ROWS, cl.mirror.shape[1] + 1), float(cap_d))
+        block[:dirty.size, :-1] = pack(cl, dirty)
+        block[:dirty.size, -1] = dirty
+        cl.mirror = _scatter_rows_jit(cl.mirror, block)
+        sent = int(dirty.size)
+    else:
+        sent = 0
+    cl.dirty[:] = False
+    return sent
+
+
 @functools.partial(jax.jit, static_argnames=("hw",))
-def _alloc_all_jit(hw: HardwareSpec, mask, n, ca, b, r0, budget_ms,
-                   k_act0, power0, cache0, t_io, t_schk,
-                   power_sum, cache_sum, d,
-                   cw, bn, r_lower, budget_new):
+def _alloc_all_jit(hw: HardwareSpec, state, new):
     """One newcomer vs every open device, full Alg. 2 grant loop.
+
+    ``state`` is the packed cluster (`pack`); ``new`` is the newcomer's
+    coefficients in `COEFF_FIELDS` order, then its batch, ``r_lower``,
+    its inference budget and the open device count ``d``.  Counts
+    travel as float64 and are rounded back to integers here.
 
     Shapes are the cluster CAPACITIES; ``d`` is traced and
     ``row_valid`` masks the padding rows (they start inactive and
@@ -198,6 +273,19 @@ def _alloc_all_jit(hw: HardwareSpec, mask, n, ca, b, r0, budget_ms,
     took, and the number of loop iterations (the numpy loop's count).
     The caller replays those grants on the host (`alloc_all_jax`).
     """
+    f = _unpack(state)
+    ca = tuple(f[name] for name in pmv.COEFF_FIELDS)
+    b, r0, budget_ms = f["b"], f["r"], f["budget_ms"]
+    mask = f["mask"] > 0.5
+    n = jnp.round(f["n"]).astype(jnp.int64)
+    k_act0, power0, cache0 = f["k_act"], f["power"], f["cache"]
+    t_load, t_feedback, t_schk = f["t_load"], f["t_feedback"], f["t_schk"]
+    power_sum, cache_sum = f["power_sum"], f["cache_sum"]
+    n_cw = len(pmv.COEFF_FIELDS)
+    cw = tuple(new[i] for i in range(n_cw))
+    bn, r_lower, budget_new = new[n_cw], new[n_cw + 1], new[n_cw + 2]
+    d = jnp.round(new[n_cw + 3])
+
     cap_d = mask.shape[0]
     row_valid = jnp.arange(cap_d) < d
 
@@ -241,7 +329,7 @@ def _alloc_all_jit(hw: HardwareSpec, mask, n, ca, b, r0, budget_ms,
         t_act = ka * (1.0 + ca[_F["alpha_cache"]] * other_res)
         t_sch = t_schk + ds[:, None] * ca[_F["n_kernels"]]
         t_gpu = (t_sch + t_act) / slow[:, None]
-        t_inf = t_io[:, :, 0] + t_gpu + t_io[:, :, 1]
+        t_inf = t_load + t_gpu + t_feedback
         viol_res = mask & (t_inf > budget_ms + 1e-9) & act[:, None]
 
         other_new = c_sum - cn
@@ -303,6 +391,8 @@ def alloc_all_jax(cl: "pmv.VecCluster", spec: WorkloadSpec,
     oracle's bits whatever float64 the device has (a TPU emulates it at
     ~2**-48 relative: every value that crossed it came back ulps off).
 
+    The cluster reaches the device through `sync` (its dirty rows, or
+    the whole state), the number of rows copied into ``cl.rows_sent``.
     While a profiler trace records, the loop's iteration count is
     fetched too, into ``cl.iters``, its copy started before the fetch so
     that it overlaps it; otherwise it stays on the device.
@@ -314,12 +404,10 @@ def alloc_all_jax(cl: "pmv.VecCluster", spec: WorkloadSpec,
     hw = cl.hw
     with trace.span("alloc_all.launch"):
         budget_new = cl.bm.budget_ms(spec.slo_ms, spec.rate_rps, batch)
-        out = _alloc_all_jit(
-            hw, cl.mask, cl.n, _coeff_arrays(cl.ca), cl.b, cl.r,
-            cl.budget_ms, cl.k_act, cl.power, cl.cache, cl.t_io, cl.t_schk,
-            cl.power_sum, cl.cache_sum, np.int64(d),
-            _coeff_scalars(coeffs), np.float64(batch), np.float64(r_lower),
-            np.float64(budget_new))
+        cl.rows_sent = sync(cl)
+        new = np.array(_coeff_scalars(coeffs)
+                       + (batch, r_lower, budget_new, d), dtype=np.float64)
+        out = _alloc_all_jit(hw, cl.mirror, new)
     counting = trace.active()
     if counting:
         out[3].copy_to_host_async()

@@ -237,6 +237,13 @@ class VecCluster:
         # Alg. 2 loop iterations of the last `alloc_all` call; the jax
         # backend fetches the count only while a profiler trace records
         self.iters: Optional[int] = None
+        # Rows changed since a device backend last copied the state
+        # (every mutator marks the rows it touched), that backend's
+        # device copy (`perf_model_jax.sync` keeps it; a capacity change
+        # drops it), and the rows its last `alloc_all` call copied.
+        self.dirty = np.ones(cap_d, dtype=bool)
+        self.mirror = None
+        self.rows_sent: Optional[int] = None
 
     # -- capacity management ------------------------------------------------
 
@@ -277,6 +284,8 @@ class VecCluster:
             out = np.zeros(self._cap_d)
             out[:a.shape[0]] = a
             setattr(self, name, out)
+        self.dirty = np.ones(self._cap_d, dtype=bool)
+        self.mirror = None
 
     # -- mutation -----------------------------------------------------------
 
@@ -284,6 +293,7 @@ class VecCluster:
         self._grow(self.d + 1, 1)
         self.entries.append([])
         self.d += 1
+        self.dirty[self.d - 1] = True
         return self.d - 1
 
     def add_entry(self, q: int, spec: WorkloadSpec,
@@ -324,6 +334,7 @@ class VecCluster:
                          for q, i in zip(rows, cols)])
         self.budget_ms[rows, cols] = self.bm.budget_ms_vec(
             slo, rate, self.b[rows, cols])
+        self.dirty[rows] = True
 
     def remove_entry(self, q: int, i: int) -> None:
         """Remove resident i from device q (workload departure /
@@ -354,7 +365,9 @@ class VecCluster:
         self._refresh_row(q)
 
     def _refresh_row(self, q: int) -> None:
-        """Recompute the cached solo invariants + sums for one device."""
+        """Recompute the cached solo invariants + sums for one device,
+        the last step of every mutator that touches it: marks it dirty."""
+        self.dirty[q] = True
         k = int(self.n[q])
         if k == 0:
             self.power_sum[q] = self.cache_sum[q] = 0.0
@@ -429,10 +442,11 @@ class VecCluster:
 
         The call is the profiler span ``igniter.alloc_all``, which
         carries the loop's iteration count ``iters`` while a trace
-        records.
+        records, and on the jax backend ``rows_sent``, the device rows
+        the call copied to the device.
         """
         with trace.span("alloc_all") as sp:
-            self.iters = None
+            self.iters = self.rows_sent = None
             if self.d == 0:
                 z = np.zeros(0)
                 return z.astype(bool), np.zeros((0, 1)), z, z
@@ -442,8 +456,10 @@ class VecCluster:
                                                    batch, r_lower)
             else:
                 out = self._alloc_all_numpy(spec, coeffs, batch, r_lower)
-            if self.iters is not None and trace.active():
-                sp.set(iters=self.iters)
+            if trace.active():
+                sp.set(**{k: v for k, v in (("iters", self.iters),
+                                            ("rows_sent", self.rows_sent))
+                          if v is not None})
             return out
 
     def _alloc_all_numpy(self, spec: WorkloadSpec,

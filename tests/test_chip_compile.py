@@ -63,17 +63,24 @@ def _coeff_tuple(sharding, shape):
     return tuple(_sds(sharding, shape) for _ in pmv.COEFF_FIELDS)
 
 
+def _state_width():
+    from repro.core import perf_model_jax as pmj
+    return len(pmj.SLOT_FIELDS) * CAP_N + len(pmj.ROW_FIELDS)
+
+
 def test_alloc_all_compiles_for_v5e(one_chip):
     from repro.core import perf_model_jax as pmj
+    from repro.core import perf_model_vec as pmv
     from repro.core.types import V5E
-    dn = (CAP_D, CAP_N)
-    f = lambda shape=dn: _sds(one_chip, shape)     # noqa: E731
     _compile(pmj._alloc_all_jit, V5E,
-             _sds(one_chip, dn, "bool"), _sds(one_chip, (CAP_D,), "int64"),
-             _coeff_tuple(one_chip, dn), f(), f(), f(), f(), f(), f(),
-             f((CAP_D, CAP_N, 2)), f(), f((CAP_D,)), f((CAP_D,)),
-             _sds(one_chip, (), "int64"),
-             _coeff_tuple(one_chip, ()), f(()), f(()), f(()))
+             _sds(one_chip, (CAP_D, _state_width())),
+             _sds(one_chip, (len(pmv.COEFF_FIELDS) + 4,)))
+
+
+def test_dirty_row_scatter_compiles_for_v5e(one_chip):
+    from repro.core import perf_model_jax as pmj
+    _compile(pmj._scatter_rows_jit, _sds(one_chip, (CAP_D, _state_width())),
+             _sds(one_chip, (pmj.K_ROWS, _state_width() + 1)))
 
 
 @pytest.mark.parametrize("n_co", [1, 2, 4])

@@ -140,6 +140,159 @@ def test_alloc_all_jax_exact_under_device_float_noise(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# The grant loop's device-resident cluster state
+# ---------------------------------------------------------------------------
+
+def _bits(a):
+    return np.ascontiguousarray(a, dtype=np.float64).view(np.int64)
+
+
+def _random_edit(rng, cls, profiles, step):
+    """One random mutation, the same on every cluster of ``cls``: a new
+    device, an entry (often on the first device, so that its row
+    outgrows four slots), a new allocation row, a departure or a budget
+    swap."""
+    ref = cls[0]
+    live = [q for q in range(ref.d) if ref.n[q]]
+    op = rng.choice(["device", "entry", "entry", "entry", "r", "remove",
+                     "budget"])
+    if op == "device" or not ref.d:
+        for cl in cls:
+            cl.add_device()
+    elif op == "entry" or not live:
+        q = 0 if rng.random() < 0.4 else int(rng.integers(0, ref.d))
+        m = str(rng.choice(["light", "mid", "heavy"]))
+        s = WorkloadSpec(f"R{step}", m, float(rng.uniform(80, 400)),
+                         float(rng.uniform(5, 60)))
+        b = int(rng.integers(1, 17))
+        r = float(rng.choice([0.05, 0.1, 0.2]))
+        for cl in cls:
+            cl.add_entry(q, s, profiles[m], b, r)
+    elif op == "r":
+        q = int(rng.choice(live))
+        row = rng.choice([0.05, 0.1, 0.15, 0.2, 0.25], size=int(ref.n[q]))
+        for cl in cls:
+            cl.set_row_r(q, row)
+    elif op == "remove":
+        q = int(rng.choice(live))
+        i = int(rng.integers(0, ref.n[q]))
+        for cl in cls:
+            cl.remove_entry(q, i)
+    else:
+        bm = (resolve("queueing").with_burstiness(float(rng.uniform(0.5, 2)))
+              if rng.random() < 0.7 else resolve("half"))
+        for cl in cls:
+            cl.set_budget(bm)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_device_mirror_tracks_random_edits_bit_for_bit(seed):
+    """After every random edit, the device copy that the incremental
+    updates built equals the packed host state bit for bit, whichever
+    way it got there (dirty-row block or whole state, across capacity
+    doublings), and the jax grant loop gives the numpy oracle's answer."""
+    from repro.core import perf_model_jax as pmj
+    profiles = _profiles()
+    rng = np.random.default_rng(seed)
+    cl_np, cl_jx = (pmv.VecCluster(V5E, budget="queueing", backend=be)
+                    for be in ("numpy", "jax"))
+    cl_np.add_device()
+    cl_jx.add_device()
+    paths = set()
+    for step in range(120):
+        _random_edit(rng, (cl_np, cl_jx), profiles, step)
+        dirty = int(cl_jx.dirty.sum())
+        whole = cl_jx.mirror is None or dirty > pmj.K_ROWS
+        m = str(rng.choice(["light", "mid", "heavy"]))
+        s_new = WorkloadSpec("NEW", m, float(rng.uniform(80, 400)),
+                             float(rng.uniform(5, 60)))
+        try:
+            b = prov.appropriate_batch(s_new, profiles[m], V5E)
+            rl = prov.resource_lower_bound(s_new, profiles[m], V5E, b)
+        except prov.InfeasibleError:
+            sent = pmj.sync(cl_jx)
+        else:
+            _assert_oracle_bits_each(
+                cl_np.alloc_all(s_new, profiles[m], b, rl),
+                cl_jx.alloc_all(s_new, profiles[m], b, rl))
+            sent = cl_jx.rows_sent
+        assert sent == (cl_jx.mask.shape[0] if whole else dirty)
+        paths.add("whole" if whole else "rows" if dirty else "none")
+        assert not cl_jx.dirty.any()
+        np.testing.assert_array_equal(_bits(cl_jx.mirror),
+                                      _bits(pmj.pack(cl_jx)))
+    assert cl_jx.mask.shape[0] >= 16 and cl_jx.mask.shape[1] >= 8
+    assert paths == {"whole", "rows"} or paths == {"whole", "rows", "none"}
+
+
+def _assert_oracle_bits_each(a, b):
+    (fa, rra, rna, ia), (fb, rrb, rnb, ib) = a, b
+    np.testing.assert_array_equal(fb, fa)
+    np.testing.assert_array_equal(rrb[fa], rra[fa])
+    np.testing.assert_array_equal(rnb[fa], rna[fa])
+    np.testing.assert_array_equal(ib, ia)
+
+
+def test_steady_provision_sends_one_row_per_call(monkeypatch, tmp_path):
+    """Under a recording trace, the ``rows_sent`` counter of each jax
+    grant-loop call of a provision: the whole state on the first call
+    and after each capacity change, else the one row that Alg. 1 changed
+    since the previous call, never more than K_ROWS."""
+    from repro.core import perf_model_jax as pmj
+    from repro.core.experiments import fitted_context
+    from repro.serving.workload import synthetic_workloads
+    from tests.test_trace_spans import _traced
+    ctx = fitted_context("tpu-v5e")
+    specs = synthetic_workloads(60, seed=1)
+    shapes = []
+    real = pmv.VecCluster.alloc_all
+
+    def alloc_all(self, *args):
+        shapes.append(self.mask.shape)
+        return real(self, *args)
+    monkeypatch.setattr(pmv.VecCluster, "alloc_all", alloc_all)
+    _, spans = _traced(lambda: prov.provision(
+        specs, ctx.profiles, ctx.hw, config=PlannerConfig(backend="jax")),
+        tmp_path)
+    sent = [s[3]["rows_sent"] for s in spans if s[2] == "alloc_all"]
+    assert len(sent) == len(shapes) >= 60
+    assert shapes[-1][0] >= 32
+    steady = 0
+    for i, (n, shape) in enumerate(zip(sent, shapes)):
+        if i == 0 or shape != shapes[i - 1]:
+            assert n == shape[0], (i, shape)
+        else:
+            assert n == 1 <= pmj.K_ROWS, (i, n)
+            steady += 1
+    assert steady > len(sent) / 2
+
+
+def test_numpy_planner_leaves_jax_module_and_x64_alone():
+    """A numpy-backend provision, in a fresh process, imports no jax
+    twin and leaves float64 off: the mirror lives on the jax side."""
+    import os
+    import subprocess
+    import sys
+    code = (
+        "import sys, jax\n"
+        "from repro.core import provisioner as prov\n"
+        "from repro.core.experiments import fitted_context\n"
+        "from repro.serving.workload import synthetic_workloads\n"
+        "ctx = fitted_context('tpu-v5e')\n"
+        "plan = prov.provision(synthetic_workloads(20, seed=0),"
+        " ctx.profiles, ctx.hw)\n"
+        "assert plan.n_gpus > 0\n"
+        "assert 'repro.core.perf_model_jax' not in sys.modules\n"
+        "assert not jax.config.jax_enable_x64\n")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in ("src", env.get("PYTHONPATH")) if p)
+    subprocess.run([sys.executable, "-c", code], check=True, env=env,
+                   cwd=os.path.dirname(os.path.dirname(__file__)),
+                   timeout=300)
+
+
+# ---------------------------------------------------------------------------
 # Plan identity: backend="jax" end to end
 # ---------------------------------------------------------------------------
 
