@@ -89,6 +89,14 @@ class CoeffArrays:
         return ((self.k1 * b * b + self.k2 * b + self.k3) / (r + self.k4)
                 + self.k5)
 
+    def solo(self, b: np.ndarray, r: np.ndarray
+             ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Each workload's solo ``(k_act, power, cache_util)``."""
+        k_act = self.k_act(b, r)
+        ability = b / k_act
+        return (k_act, self.alpha_power * ability + self.beta_power,
+                self.alpha_cacheutil * ability + self.beta_cacheutil)
+
 
 # ---------------------------------------------------------------------------
 # Batched forward evaluation of Eqs. (1)-(11)
@@ -245,6 +253,60 @@ class VecCluster:
         self.mirror = None
         self.rows_sent: Optional[int] = None
 
+    @classmethod
+    def from_devices(cls, hw: HardwareSpec,
+                     devices: Sequence[Sequence[Tuple[WorkloadSpec,
+                                                      WorkloadCoefficients,
+                                                      int, float]]],
+                     budget: BudgetLike = QUEUEING,
+                     backend: str = "numpy") -> "VecCluster":
+        """A cluster whose row q holds the residents ``(spec, coeffs,
+        batch, r)`` of ``devices[q]``, in order, built in one vectorised
+        pass.  Capacities, entries and every array equal, bit for bit,
+        what `add_device` and `add_entry` called for each in turn give."""
+        cl = cls(hw, budget=budget, backend=backend)
+        d = len(devices)
+        n = np.fromiter(map(len, devices), dtype=np.int64, count=d)
+        cl._grow(d, int(n.max(initial=0)))
+        cl.d = d
+        cl.n[:d] = n
+        cl.entries = [[(s, c, b) for s, c, b, _ in ws] for ws in devices]
+        if not n.any():
+            return cl
+        specs, coeffs, batch, r = zip(*(e for ws in devices for e in ws))
+        rows = np.repeat(np.arange(d), n)
+        cols = np.arange(rows.size) - np.repeat(np.cumsum(n) - n, n)
+        # the few distinct coefficient objects, stacked once
+        distinct = {id(c): c for c in coeffs}
+        pos = {key: j for j, key in enumerate(distinct)}
+        table = CoeffArrays.stack(list(distinct.values()))
+        which = np.fromiter((pos[id(c)] for c in coeffs), dtype=np.intp,
+                            count=rows.size)
+        ca = CoeffArrays(**{f: getattr(table, f)[which]
+                            for f in COEFF_FIELDS})
+        b = np.array(batch, dtype=np.float64)
+        r = np.array(r, dtype=np.float64)
+        for f in COEFF_FIELDS:
+            getattr(cl.ca, f)[rows, cols] = getattr(ca, f)
+        cl.b[rows, cols] = b
+        cl.r[rows, cols] = r
+        cl.budget_ms[rows, cols] = [
+            cl.bm.budget_ms(s.slo_ms, s.rate_rps, x)
+            for s, x in zip(specs, batch)]
+        cl.mask[rows, cols] = True
+        (cl.k_act[rows, cols], cl.power[rows, cols],
+         cl.cache[rows, cols]) = ca.solo(b, r)
+        cl.t_io[rows, cols, 0] = ca.d_load * b / hw.pcie_bw
+        cl.t_io[rows, cols, 1] = ca.d_feedback * b / hw.pcie_bw
+        cl.t_schk[rows, cols] = ca.k_sch * ca.n_kernels
+        # `_refresh_row` sums a row's first k slots; numpy's pairwise sum
+        # over k elements is reproduced only by a reduction over k columns
+        for k in np.unique(n[n > 0]):
+            sel = np.flatnonzero(n == k)
+            cl.power_sum[sel] = cl.power[sel, :k].sum(axis=1)
+            cl.cache_sum[sel] = cl.cache[sel, :k].sum(axis=1)
+        return cl
+
     # -- capacity management ------------------------------------------------
 
     def _grow(self, need_d: int, need_n: int) -> None:
@@ -375,12 +437,8 @@ class VecCluster:
         sl = np.s_[q, :k]
         ca_row = CoeffArrays(**{f: getattr(self.ca, f)[sl]
                                 for f in COEFF_FIELDS})
-        k_act = ca_row.k_act(self.b[sl], self.r[sl])
-        ability = self.b[sl] / k_act
-        self.k_act[sl] = k_act
-        self.power[sl] = ca_row.alpha_power * ability + ca_row.beta_power
-        self.cache[sl] = (ca_row.alpha_cacheutil * ability
-                          + ca_row.beta_cacheutil)
+        self.k_act[sl], self.power[sl], self.cache[sl] = ca_row.solo(
+            self.b[sl], self.r[sl])
         self.power_sum[q] = self.power[sl].sum()
         self.cache_sum[q] = self.cache[sl].sum()
 
@@ -554,10 +612,8 @@ class VecCluster:
                 rr[rows, cols] = np.round(rr[rows, cols] + hw.r_unit, 10)
                 ca_g = CoeffArrays(**{f: getattr(self.ca, f)[rows, cols]
                                       for f in COEFF_FIELDS})
-                k_act = ca_g.k_act(self.b[rows, cols], rr[rows, cols])
-                ability = self.b[rows, cols] / k_act
-                p_new = ca_g.alpha_power * ability + ca_g.beta_power
-                c_new = ca_g.alpha_cacheutil * ability + ca_g.beta_cacheutil
+                k_act, p_new, c_new = ca_g.solo(self.b[rows, cols],
+                                                rr[rows, cols])
                 np.subtract.at(p_sum, rows, pw[rows, cols] - p_new)
                 np.subtract.at(c_sum, rows, cu[rows, cols] - c_new)
                 ka[rows, cols] = k_act

@@ -704,12 +704,10 @@ def add_workload(plan: ProvisioningPlan, spec: WorkloadSpec,
         cand = devs if not exclude_gpus else \
             {g: d for g, d in devs.items() if g not in exclude_gpus}
         if cfg.engine == "vec":
-            cl = pmv.VecCluster(hw, budget=bm, backend=cfg.backend)
             gpu_ids = sorted(cand)
-            for g in gpu_ids:
-                q = cl.add_device()
-                for (s, cc, bb, r) in cand[g].entries:
-                    cl.add_entry(q, s, cc, bb, r)
+            cl = pmv.VecCluster.from_devices(
+                hw, [cand[g].entries for g in gpu_ids], budget=bm,
+                backend=cfg.backend)
 
     best_q, best_alloc, best_inter = -1, None, R_MAX + 1.0
     if cfg.engine == "vec":

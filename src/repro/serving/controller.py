@@ -683,19 +683,19 @@ class PlanState:
         self.max_devices = max_devices
         self.hardware = plan.hardware or hw
         self.probes = probes
-        self.cl = pmv.VecCluster(hw, budget=budget, backend=backend)
-        self.row_gpus: List[int] = []          # row q -> plan gpu id
-        self.home: Dict[str, int] = {}         # workload name -> row q
         by_gpu: Dict[int, List[Placement]] = {}
         for p in plan.placements:
             by_gpu.setdefault(p.gpu, []).append(p)
-        for g in sorted(by_gpu):               # add_workload's row order
-            q = self.cl.add_device()
-            self.row_gpus.append(g)
-            for p in by_gpu[g]:
-                self.cl.add_entry(q, p.workload,
-                                  profiles[p.workload.model], p.batch, p.r)
-                self.home[p.workload.name] = q
+        # row q -> plan gpu id, in add_workload's row order
+        self.row_gpus: List[int] = sorted(by_gpu)
+        # workload name -> row q
+        self.home: Dict[str, int] = {
+            p.workload.name: q for q, g in enumerate(self.row_gpus)
+            for p in by_gpu[g]}
+        self.cl = pmv.VecCluster.from_devices(
+            hw, [[(p.workload, profiles[p.workload.model], p.batch, p.r)
+                  for p in by_gpu[g]] for g in self.row_gpus],
+            budget=budget, backend=backend)
         self._next_gpu = (max(by_gpu) + 1) if by_gpu else 0
         # plan gpu ids placement must avoid (health-layer quarantine);
         # the Reconciler keeps this in sync with its quarantine set

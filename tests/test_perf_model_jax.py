@@ -225,6 +225,34 @@ def test_device_mirror_tracks_random_edits_bit_for_bit(seed):
     assert paths == {"whole", "rows"} or paths == {"whole", "rows", "none"}
 
 
+def test_one_pass_cluster_packs_and_places_as_the_loop():
+    """An arrival's cluster built in one pass packs, for the device, to
+    the loop-built cluster's bits, and a jax-backend `add_workload` on a
+    standing m=100 plan returns the numpy backend's plan."""
+    from repro.core import perf_model_jax as pmj
+    from repro.core.experiments import fitted_context
+    from repro.serving.workload import synthetic_workloads
+    from tests.test_perf_model_vec import loop_cluster, random_residents
+    devices = random_residents(np.random.default_rng(11), 20, (1, 4, 5, 9))
+    for budget in ("half", "queueing"):
+        ref = loop_cluster(devices, budget, backend="jax")
+        got = pmv.VecCluster.from_devices(V5E, devices, budget=budget,
+                                          backend="jax")
+        np.testing.assert_array_equal(_bits(pmj.pack(got)),
+                                      _bits(pmj.pack(ref)))
+    ctx = fitted_context("tpu-v5e")
+    specs = synthetic_workloads(101, seed=2)
+    plan = prov.provision(specs[:100], ctx.profiles, ctx.hw)
+    for spec in specs[100:] + [WorkloadSpec("EXTRA", specs[0].model,
+                                            specs[0].slo_ms, 25.0)]:
+        np_plan, jx_plan = (
+            prov.add_workload(plan, spec, ctx.profiles, ctx.hw,
+                              config=PlannerConfig(backend=be))
+            for be in ("numpy", "jax"))
+        assert plan_key(jx_plan) == plan_key(np_plan)
+        plan = np_plan
+
+
 def _assert_oracle_bits_each(a, b):
     (fa, rra, rna, ia), (fb, rrb, rnb, ib) = a, b
     np.testing.assert_array_equal(fb, fa)

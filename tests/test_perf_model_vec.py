@@ -146,6 +146,71 @@ def test_veccluster_incremental_matches_fresh():
             np.testing.assert_allclose(wb.t_inf, wa.t_inf, **TOL)
 
 
+def random_residents(rng, n_devices, sizes):
+    """``n_devices`` resident lists ``(spec, coeffs, batch, r)`` of the
+    pinned profiles, row sizes drawn from ``sizes`` (each one used)."""
+    profiles = _profiles()
+    counts = list(sizes) + list(rng.choice(sizes,
+                                           size=n_devices - len(sizes)))
+    rng.shuffle(counts)
+    devices = []
+    for q, k in enumerate(counts):
+        row = []
+        for i in range(int(k)):
+            m = str(rng.choice(["light", "mid", "heavy"]))
+            s = WorkloadSpec(f"W{q}_{i}", m, float(rng.uniform(60, 400)),
+                             float(rng.uniform(5, 300)))
+            row.append((s, profiles[m], int(rng.integers(1, 33)),
+                        float(rng.choice([0.05, 0.1, 0.2, 0.25, 0.4]))))
+        devices.append(row)
+    return devices
+
+
+def loop_cluster(devices, budget, backend="numpy"):
+    """The cluster built one `add_device` / `add_entry` call at a time."""
+    cl = pmv.VecCluster(V5E, budget=budget, backend=backend)
+    for row in devices:
+        q = cl.add_device()
+        for (s, c, b, r) in row:
+            cl.add_entry(q, s, c, b, r)
+    return cl
+
+
+CLUSTER_ARRAYS = ("b", "r", "budget_ms", "mask", "n", "k_act", "power",
+                  "cache", "t_io", "t_schk", "power_sum", "cache_sum")
+
+
+@pytest.mark.parametrize("budget", ["half", "queueing"])
+@pytest.mark.parametrize("n_devices,sizes", [
+    (5, (1, 4)),              # capacities stay (8, 4)
+    (12, (1, 4, 5)),          # rows cross 8, slots 4 -> 8
+    (20, (1, 4, 5, 9)),       # rows cross 16, slots 8 -> 16, pairwise sums
+    (17, (0, 1, 9, 12, 17)),  # empty rows, slots 16 -> 32
+])
+def test_from_devices_matches_add_entry_bit_for_bit(n_devices, sizes,
+                                                    budget):
+    """The one-pass constructor gives the incremental build's state bit
+    for bit: capacities, every coefficient field and cached array
+    (numpy's pairwise row sums past 8 residents included), entries,
+    counts, and a fresh cluster's all-dirty rows with no device copy."""
+    rng = np.random.default_rng([n_devices, len(sizes)])
+    devices = random_residents(rng, n_devices, sizes)
+    ref = loop_cluster(devices, budget)
+    got = pmv.VecCluster.from_devices(V5E, devices, budget=budget)
+    for f in pmv.COEFF_FIELDS:
+        a, b = getattr(ref.ca, f), getattr(got.ca, f)
+        assert a.shape == b.shape and a.tobytes() == b.tobytes(), f
+    for f in CLUSTER_ARRAYS:
+        a, b = getattr(ref, f), getattr(got, f)
+        assert a.shape == b.shape and a.dtype == b.dtype, f
+        assert a.tobytes() == b.tobytes(), f
+    assert got.entries == ref.entries
+    assert got.d == ref.d == n_devices
+    assert got.dirty.shape == ref.dirty.shape and got.dirty.all()
+    assert got.mirror is None
+    assert got.mask.shape[1] >= max(sizes)
+
+
 # ---------------------------------------------------------------------------
 # Algorithm 2: batched == scalar
 # ---------------------------------------------------------------------------
