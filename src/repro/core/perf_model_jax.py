@@ -1,24 +1,17 @@
-"""JAX-jitted twins of the batched iGniter model and budget solver.
+"""The Alg. 2 grant loop as a jitted XLA program (``backend="jax"``).
 
 `repro.core.perf_model_vec` is the numpy hot path and stays the pinned
-oracle; this module re-expresses its three inner loops as jitted XLA
-programs for the m=10,000 tier:
+oracle.  This module runs `VecCluster.alloc_all`'s loop, one newcomer
+against every open device, on the accelerator, for every placement
+path (Alg. 1, `add_workload`, the controller's `PlanState`):
 
-  * ``predict_device_batch_jax``  Eqs. (1)-(11) over padded (D, N)
-                                  device arrays under `jax.jit` (the
-                                  `perf_model_vec._eval` twin)
-  * ``budget_ms_vec_jax``         the queueing-aware SLO budget split as
-                                  a fixed-iteration `lax.fori_loop`
-                                  bisection (`queueing.budget_ms_vec`
-                                  twin — SOLVE_ITERS halvings, same
-                                  bracket, same cap at T_slo/2)
-  * ``alloc_all_jax``             Algorithm 2 against every open device
-                                  as ONE `lax.while_loop` with
-                                  fixed-capacity shapes (the
-                                  `VecCluster.alloc_all` twin), driving
-                                  both Alg. 1 placement and the
-                                  controller's feasibility probes when
-                                  `PlannerConfig(backend="jax")`
+  * ``pack`` / ``sync``       the cluster as one packed float64 array
+                              kept on the device (see Residency)
+  * ``_scatter_rows_jit``     dirty rows written into that copy
+  * ``_alloc_all_jit``        Algorithm 2 against every open device as
+                              ONE `lax.while_loop`; returns grant counts
+  * ``alloc_all_jax``         the dispatch target: sync, launch, fetch,
+                              and the host replay of the grants
 
 Layout contract: shapes are the VecCluster capacities (powers of two),
 NOT the live device count d — d arrives as a traced scalar and
@@ -55,7 +48,7 @@ noise.  Importing this module enables jax x64 mode process-wide.
 from __future__ import annotations
 
 import functools
-from typing import List, Optional, Sequence, Tuple
+from typing import Tuple
 
 import numpy as np
 
@@ -66,11 +59,8 @@ jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp  # noqa: E402  (after x64 switch on purpose)
 from jax import lax  # noqa: E402
 
-from repro.core import perf_model as pm  # noqa: E402
 from repro.core import perf_model_vec as pmv  # noqa: E402
 from repro.core import trace  # noqa: E402
-from repro.core.queueing import (  # noqa: E402
-    RHO_MAX, SOLVE_ITERS, BudgetModel)
 from repro.core.types import (  # noqa: E402
     HardwareSpec, WorkloadCoefficients, WorkloadSpec)
 
@@ -95,102 +85,10 @@ def _coeff_scalars(c: WorkloadCoefficients) -> Tuple[float, ...]:
     return tuple(float(getattr(c, f)) for f in pmv.COEFF_FIELDS)
 
 
-def _coeff_arrays(ca: pmv.CoeffArrays) -> Tuple[np.ndarray, ...]:
-    return tuple(getattr(ca, f) for f in pmv.COEFF_FIELDS)
-
-
 def _k_act(ca, b, r):
     """Eq. (11) on a flat coefficient tuple."""
     return ((ca[_F["k1"]] * b * b + ca[_F["k2"]] * b + ca[_F["k3"]])
             / (r + ca[_F["k4"]]) + ca[_F["k5"]])
-
-
-# ---------------------------------------------------------------------------
-# Eqs. (1)-(11), jitted
-# ---------------------------------------------------------------------------
-
-@functools.partial(jax.jit, static_argnames=("hw",))
-def _eval_jit(ca, b, r, mask, hw: HardwareSpec):
-    """`perf_model_vec._eval` under jit: identical formula sequence."""
-    k_act = _k_act(ca, b, r)
-    ability = jnp.where(mask, b / k_act, 0.0)
-    power = jnp.where(mask, ca[_F["alpha_power"]] * ability
-                      + ca[_F["beta_power"]], 0.0)
-    cache = jnp.where(mask, ca[_F["alpha_cacheutil"]] * ability
-                      + ca[_F["beta_cacheutil"]], 0.0)
-
-    n_co = mask.sum(axis=-1)
-    ds = jnp.where(n_co <= 1, 0.0, hw.alpha_sch * n_co + hw.beta_sch)
-    p_demand = hw.idle_power + power.sum(axis=-1)
-    freq = jnp.where(p_demand <= hw.power_cap, hw.max_freq,
-                     jnp.maximum(hw.max_freq
-                                 + hw.alpha_f * (p_demand - hw.power_cap),
-                                 0.3 * hw.max_freq))
-    slowdown = freq / hw.max_freq
-
-    other_cache = cache.sum(axis=-1)[..., None] - cache
-    t_load = ca[_F["d_load"]] * b / hw.pcie_bw
-    t_feedback = ca[_F["d_feedback"]] * b / hw.pcie_bw
-    t_sch = (ca[_F["k_sch"]] + ds[..., None]) * ca[_F["n_kernels"]]
-    t_act = k_act * (1.0 + ca[_F["alpha_cache"]] * other_cache)
-    t_gpu = (t_sch + t_act) / slowdown[..., None]
-    t_inf = t_load + t_gpu + t_feedback
-    throughput = jnp.where(mask, 1000.0 * b / (t_gpu + t_feedback), 0.0)
-    return (freq, p_demand, ds, t_load, t_sch, t_act, t_gpu,
-            t_feedback, t_inf, throughput)
-
-
-def predict_device_batch_jax(devices: Sequence[Sequence[pm.PlacedWorkload]],
-                             hw: HardwareSpec) -> pmv.BatchPrediction:
-    """Jitted drop-in for `perf_model_vec.predict_device_batch`."""
-    ca, b, r, mask = pmv._pad_stack(devices)
-    out = _eval_jit(_coeff_arrays(ca), b, r, mask, hw)
-    (freq, p_demand, ds, t_load, t_sch, t_act, t_gpu,
-     t_feedback, t_inf, throughput) = (np.asarray(a) for a in out)
-    return pmv.BatchPrediction(
-        mask=mask, freq=freq, p_demand=p_demand, delta_sch=ds,
-        t_load=t_load, t_sch=t_sch, t_act=t_act, t_gpu=t_gpu,
-        t_feedback=t_feedback, t_inf=t_inf, throughput=throughput)
-
-
-# ---------------------------------------------------------------------------
-# Queueing-aware budget split, jitted bisection
-# ---------------------------------------------------------------------------
-
-@jax.jit
-def _budget_bisect_jit(slo, rate, batch, quantile, slack_frac, burstiness):
-    """`queueing.budget_ms_vec`'s fixed-iteration bisection under jit."""
-    r_ms = rate / 1000.0
-    b = batch
-    target = slo * (1.0 - slack_frac)
-    qf = -jnp.log1p(-quantile)
-
-    def body(_, lohi):
-        lo, hi = lohi
-        mid = 0.5 * (lo + hi)
-        rho = r_ms * mid / b
-        w = burstiness * rho * mid / (2.0 * b * (1.0 - rho))
-        tail = jnp.where(rho >= RHO_MAX, jnp.inf, (b - 1.0) / r_ms + w * qf)
-        tail = jnp.where(r_ms > 0.0, tail, 0.0)
-        ok = mid + tail <= target
-        return jnp.where(ok, mid, lo), jnp.where(ok, hi, mid)
-
-    lo, hi = lax.fori_loop(0, SOLVE_ITERS, body,
-                           (jnp.zeros_like(slo), slo))
-    return jnp.minimum(lo, slo / 2.0)
-
-
-def budget_ms_vec_jax(bm: BudgetModel, slo_ms, rate_rps, batch) -> np.ndarray:
-    """Batched budget split on the JAX backend (numpy arrays in/out)."""
-    slo = np.asarray(slo_ms, dtype=np.float64)
-    if bm.mode == "half":
-        return slo / 2.0
-    out = _budget_bisect_jit(slo, np.asarray(rate_rps, dtype=np.float64),
-                             np.asarray(batch, dtype=np.float64),
-                             np.float64(bm.quantile),
-                             np.float64(bm.slack_frac),
-                             np.float64(bm.burstiness))
-    return np.asarray(out)
 
 
 # ---------------------------------------------------------------------------

@@ -21,7 +21,7 @@ from __future__ import annotations
 import math
 import dataclasses
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import AbstractSet, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -632,6 +632,38 @@ def _rebalance_replica_shares(plan: ProvisioningPlan,
 # workloads on the chosen device to absorb the newcomer's interference.
 # ---------------------------------------------------------------------------
 
+def _choose_row(cl: pmv.VecCluster, row_gpus: Sequence[int], sweep: tuple,
+                name: str, *, banned: Optional[AbstractSet[int]] = None,
+                reserved: Optional[np.ndarray] = None,
+                max_devices: Optional[int] = None) -> int:
+    """Alg. 1 lines 8-14 over an existing plan, given one `alloc_all`
+    ``sweep`` of ``cl`` (row q is plan device ``row_gpus[q]``): the row
+    the newcomer lands on, or -1 for a fresh device.
+
+    A row is out when Alg. 2 refuses it, when its device is in
+    ``banned``, when its re-solved residents + newcomer + ``reserved``
+    (armed shadow reservations per row) would pass r = 1.0, or, once
+    ``max_devices`` devices are in use, when it is empty: anything
+    landing there is one more device.  The earliest least-interference
+    row of the rest wins; with none left, the cap is checked before the
+    caller opens a fresh device."""
+    feasible, rr, rn, r_inter = sweep
+    d = cl.d
+    used = int(np.count_nonzero(cl.n[:d]))
+    out = ~feasible
+    if banned:
+        out |= np.fromiter((g in banned for g in row_gpus), dtype=bool,
+                           count=d)
+    if reserved is not None and reserved.any():
+        out |= (rr * cl.mask[:d]).sum(axis=1) + rn + reserved > 1.0 + 1e-9
+    if max_devices is not None and used >= max_devices:
+        out |= cl.n[:d] == 0
+    row = -1 if out.all() else _argmin_inter(np.where(out, np.inf, r_inter))
+    if row == -1:
+        _check_device_cap(used, max_devices, name, cl.hw)
+    return row
+
+
 @trace.spanned("add_workload")
 def add_workload(plan: ProvisioningPlan, spec: WorkloadSpec,
                  profiles: Dict[str, WorkloadCoefficients],
@@ -701,33 +733,31 @@ def add_workload(plan: ProvisioningPlan, spec: WorkloadSpec,
         for p in plan.placements:
             devs.setdefault(p.gpu, _Dev()).entries.append(
                 (p.workload, profiles[p.workload.model], p.batch, p.r))
-        cand = devs if not exclude_gpus else \
-            {g: d for g, d in devs.items() if g not in exclude_gpus}
         if cfg.engine == "vec":
-            gpu_ids = sorted(cand)
+            gpu_ids = sorted(devs)
             cl = pmv.VecCluster.from_devices(
-                hw, [cand[g].entries for g in gpu_ids], budget=bm,
+                hw, [devs[g].entries for g in gpu_ids], budget=bm,
                 backend=cfg.backend)
 
     best_q, best_alloc, best_inter = -1, None, R_MAX + 1.0
     if cfg.engine == "vec":
-        if gpu_ids:
-            feasible, rr, rn, r_inter = cl.alloc_all(spec, c, b, rl)
-            with trace.span("place"):
-                if reserved:
-                    resv = np.array([reserved.get(g, 0.0) for g in gpu_ids])
-                    if resv.any():
-                        load = (rr * cl.mask[:cl.d]).sum(axis=1) + rn + resv
-                        over = load > 1.0 + 1e-9
-                        feasible = feasible & ~over
-                        r_inter = np.where(over, np.inf, r_inter)
-                row = _argmin_inter(r_inter) if feasible.any() else -1
-                if row != -1:
-                    best_q = gpu_ids[row]
-                    k = int(cl.n[row])
-                    best_alloc = ([float(x) for x in rr[row, :k]]
-                                  + [float(rn[row])])
+        sweep = cl.alloc_all(spec, c, b, rl)
+        with trace.span("place"):
+            row = _choose_row(
+                cl, gpu_ids, sweep, spec.name,
+                banned=exclude_gpus,
+                reserved=(np.array([reserved.get(g, 0.0) for g in gpu_ids])
+                          if reserved else None),
+                max_devices=max_devices)
+            if row != -1:
+                _, rr, rn, _ = sweep
+                best_q = gpu_ids[row]
+                k = int(cl.n[row])
+                best_alloc = ([float(x) for x in rr[row, :k]]
+                              + [float(rn[row])])
     else:
+        cand = devs if not exclude_gpus else \
+            {g: d for g, d in devs.items() if g not in exclude_gpus}
         for q, dev in sorted(cand.items()):
             r_a = alloc_gpus(dev, spec, c, b, rl, hw, budget=bm)
             if r_a is None:
@@ -739,10 +769,11 @@ def add_workload(plan: ProvisioningPlan, spec: WorkloadSpec,
             r_inter = sum(max(0.0, na - oa) for na, oa in zip(r_a, old))
             if r_inter < best_inter - 1e-12:
                 best_q, best_alloc, best_inter = q, r_a, r_inter
+        if best_q == -1:
+            _check_device_cap(len(devs), max_devices, spec.name, hw)
 
     new_plan = ProvisioningPlan(hardware=plan.hardware or hw)
     if best_q == -1:
-        _check_device_cap(len(devs), max_devices, spec.name, hw)
         g_new = (max(devs) + 1) if devs else 0
         new_plan.placements = list(plan.placements) + [
             Placement(workload=spec, gpu=g_new,
@@ -788,6 +819,23 @@ def remove_workload(plan: ProvisioningPlan, name: str, *,
     return new_plan
 
 
+def _realloc_home(residents: Sequence[tuple], spec: WorkloadSpec,
+                  c: WorkloadCoefficients, b: int, rl: float,
+                  hw: HardwareSpec, bm: BudgetModel, backend: str,
+                  reserved: Optional[float]) -> Optional[List[float]]:
+    """A resize's same-device fast path: Alg. 2 for ``spec`` on its home
+    device beside ``residents``.  The allocation vector (``spec`` last),
+    or None when the device cannot host it or, with ``reserved`` (the
+    armed reservations homed there) given, the result would eat into
+    them: the caller then moves the workload."""
+    r_a = pmv.alloc_gpus_vec(residents, spec, c, b, rl, hw, budget=bm,
+                             backend=backend)
+    if (r_a is not None and reserved is not None
+            and math.fsum(r_a) + reserved > 1.0 + 1e-9):
+        return None                # the reservation holds
+    return r_a
+
+
 @trace.spanned("resize_workload")
 def resize_workload(plan: ProvisioningPlan, spec: WorkloadSpec,
                     profiles: Dict[str, WorkloadCoefficients],
@@ -830,15 +878,15 @@ def resize_workload(plan: ProvisioningPlan, spec: WorkloadSpec,
     residents = [(p.workload, profiles[p.workload.model], p.batch, p.r)
                  for p in peers]
     if cfg.engine == "vec":
-        r_a = pmv.alloc_gpus_vec(residents, spec, c, b, rl, hw, budget=bm,
-                                 backend=cfg.backend)
+        r_a = _realloc_home(residents, spec, c, b, rl, hw, bm, cfg.backend,
+                            reserved.get(cur.gpu, 0.0) if reserved else None)
     else:
         r_a = alloc_gpus(_Dev(entries=residents), spec, c, b, rl, hw,
                          budget=bm)
-    if (r_a is not None and reserved
-            and (math.fsum(float(x) for x in r_a)
-                 + reserved.get(cur.gpu, 0.0) > 1.0 + 1e-9)):
-        r_a = None                 # the reservation holds: migrate
+        if (r_a is not None and reserved
+                and (math.fsum(r_a) + reserved.get(cur.gpu, 0.0)
+                     > 1.0 + 1e-9)):
+            r_a = None                 # the reservation holds: migrate
     if r_a is None:
         return migrate_workload(plan, spec, profiles, hw,
                                 config=cfg.replace(budget=bm),
@@ -886,6 +934,177 @@ def migrate_workload(plan: ProvisioningPlan, spec: WorkloadSpec,
     return add_workload(remove_workload(plan, spec.name), spec, profiles,
                         hw, config=cfg, exclude_gpus=exclude_gpus,
                         max_devices=max_devices, reserved=reserved)
+
+
+# ---------------------------------------------------------------------------
+# Live plan editor: the same edits over a cluster kept across them
+# ---------------------------------------------------------------------------
+
+class PlanState:
+    """A live `VecCluster` mirror of a plan, edited in place.
+
+    The edits above are plan-in/plan-out and rebuild their cluster per
+    call — exact and oracle-friendly, but O(cluster) each, which at
+    m=1000 puts the controller's own latency (the Sec. 5.5 overhead
+    number) in the tens of seconds.  This mirror keeps the cluster's
+    cached invariants ALIVE across edits so each one costs only the
+    devices it touches: a same-device resize re-runs Alg. 2 against that
+    device alone (`_realloc_home`), a placement scores every device in
+    ONE `alloc_all` and decides as `add_workload` does (`_choose_row`),
+    and a departure is a single `remove_entry`.  Allocation outcomes
+    match the sequential plan edits (entry order within a device
+    differs, which the model's symmetric sums make irrelevant) — pinned
+    by `tests/test_controller.py`; emptied devices are additionally
+    reused as placement targets instead of stranding them.
+    """
+
+    def __init__(self, plan: ProvisioningPlan,
+                 profiles: Dict[str, WorkloadCoefficients],
+                 hw: HardwareSpec, budget: BudgetLike = QUEUEING,
+                 backend: str = "numpy",
+                 probes: Optional[ProbeCache] = None,
+                 max_devices: Optional[int] = None,
+                 shadow: Optional[Dict[str, float]] = None):
+        self.hw = hw
+        self.profiles = profiles
+        self.max_devices = max_devices
+        self.hardware = plan.hardware or hw
+        self.probes = probes
+        by_gpu: Dict[int, List[Placement]] = {}
+        for p in plan.placements:
+            by_gpu.setdefault(p.gpu, []).append(p)
+        # row q -> plan gpu id, in add_workload's row order
+        self.row_gpus: List[int] = sorted(by_gpu)
+        # workload name -> row q
+        self.home: Dict[str, int] = {
+            p.workload.name: q for q, g in enumerate(self.row_gpus)
+            for p in by_gpu[g]}
+        self.cl = pmv.VecCluster.from_devices(
+            hw, [[(p.workload, profiles[p.workload.model], p.batch, p.r)
+                  for p in by_gpu[g]] for g in self.row_gpus],
+            budget=budget, backend=backend)
+        self._next_gpu = (max(by_gpu) + 1) if by_gpu else 0
+        # plan gpu ids placement must avoid (health-layer quarantine);
+        # the controller keeps this in sync with its quarantine set
+        self.banned: set = set()
+        # Sec. 4.2 shadow reservations, workload name -> shadow_r.
+        # Shared BY REFERENCE with the owning controller's armed book,
+        # so every placement sweep sees the reservation the moment it
+        # is granted: an activation may push a device to r + shadow_r
+        # but never past 1.0 (tests/test_forecast.py pins this)
+        self.shadow: Dict[str, float] = shadow if shadow is not None \
+            else {}
+
+    def _row_reserved(self, exclude: Optional[str] = None) -> np.ndarray:
+        """Per-row armed shadow reservation: the capacity a monitor-tick
+        activation may claim, which placement must treat as spoken for."""
+        out = np.zeros(self.cl.d)
+        for name, sr in self.shadow.items():
+            if name == exclude:
+                continue
+            q = self.home.get(name)
+            if q is not None:
+                out[q] += sr
+        return out
+
+    def set_budget(self, budget: BudgetLike) -> None:
+        self.cl.set_budget(budget)
+
+    def remove(self, name: str) -> None:
+        q = self.home.pop(name)
+        self.cl.remove_entry(q, self._slot_at(q, name))
+
+    def _slot_at(self, q: int, name: str) -> int:
+        for i, (s, _, _) in enumerate(self.cl.entries[q]):
+            if s.name == name:
+                return i
+        raise KeyError(name)
+
+    def _place(self, spec: WorkloadSpec, c: WorkloadCoefficients,
+               b: int, rl: float) -> None:
+        """`add_workload` against the live cluster: one `alloc_all`
+        sweep, `_choose_row`, else a fresh device at `self_grant`."""
+        cl = self.cl
+        sweep = cl.alloc_all(spec, c, b, rl)
+        row = _choose_row(
+            cl, self.row_gpus, sweep, spec.name, banned=self.banned,
+            reserved=(self._row_reserved(exclude=spec.name)
+                      if self.shadow else None),
+            max_devices=self.max_devices)
+        if row == -1:
+            row = cl.add_device()
+            self.row_gpus.append(self._next_gpu)
+            self._next_gpu += 1
+            cl.add_entry(row, spec, c, b,
+                         self_grant(spec, c, b, rl, self.hw, budget=cl.bm))
+        else:
+            _, rr, rn, _ = sweep
+            cl.set_row_r(row, rr[row])
+            cl.add_entry(row, spec, c, b, float(rn[row]))
+        self.home[spec.name] = row
+
+    def _theorem1(self, spec: WorkloadSpec, c: WorkloadCoefficients,
+                  batch: str) -> tuple:
+        """(b_appr, r_lower) through the shared probe cache when one is
+        attached — repeat edits to a (spec, budget) pair skip the
+        joint-batch scan entirely."""
+        if self.probes is not None:
+            return self.probes.theorem1(spec, c, self.hw, self.cl.bm, batch)
+        b = appropriate_batch(spec, c, self.hw, budget=self.cl.bm,
+                              batch=batch)
+        rl = resource_lower_bound(spec, c, self.hw, b, budget=self.cl.bm)
+        return b, rl
+
+    def add(self, spec: WorkloadSpec, *, batch: str = "joint",
+            pin: Optional[tuple] = None) -> None:
+        """``pin=(batch, r_floor)`` bypasses Theorem 1 — the health
+        layer's capacity-preserving migration (`add_workload`
+        semantics)."""
+        c = self.profiles[spec.model]
+        if pin is not None:
+            b, rl = int(pin[0]), float(pin[1])
+        else:
+            b, rl = self._theorem1(spec, c, batch)
+        self._place(spec, c, b, rl)
+
+    def resize(self, spec: WorkloadSpec, *, batch: str = "joint") -> None:
+        """Theorem 1 at the new rate, same-device Alg. 2 re-run first,
+        placement over the live cluster as the fallback
+        (`resize_workload` semantics, O(devices touched))."""
+        c = self.profiles[spec.model]
+        b, rl = self._theorem1(spec, c, batch)
+        cl = self.cl
+        q = self.home.pop(spec.name)
+        cl.remove_entry(q, self._slot_at(q, spec.name))
+        if self.row_gpus[q] in self.banned:
+            # quarantined home device: no same-device fast path — the
+            # resize IS the eviction (min-interference move elsewhere)
+            self._place(spec, c, b, rl)
+            return
+        residents = [(s, cc, bb, float(cl.r[q, i]))
+                     for i, (s, cc, bb) in enumerate(cl.entries[q])]
+        resv = (math.fsum(sr for n2, sr in self.shadow.items()
+                          if n2 != spec.name and self.home.get(n2) == q)
+                if self.shadow else None)
+        r_a = _realloc_home(residents, spec, c, b, rl, self.hw, cl.bm,
+                            "numpy", resv)
+        if r_a is not None:
+            cl.set_row_r(q, np.array(r_a[:-1]))
+            cl.add_entry(q, spec, c, b, r_a[-1])
+            self.home[spec.name] = q
+        else:
+            self._place(spec, c, b, rl)
+
+    def to_plan(self) -> ProvisioningPlan:
+        plan = ProvisioningPlan(hardware=self.hardware)
+        cl = self.cl
+        for q in range(cl.d):
+            for i, (s, _, b) in enumerate(cl.entries[q]):
+                plan.placements.append(Placement(
+                    workload=s, gpu=self.row_gpus[q],
+                    r=float(cl.r[q, i]), batch=b))
+        plan.n_gpus = sum(1 for q in range(cl.d) if cl.entries[q])
+        return plan
 
 
 # ---------------------------------------------------------------------------

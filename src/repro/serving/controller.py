@@ -68,11 +68,10 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from repro.core import perf_model as pm
-from repro.core import perf_model_vec as pmv
 from repro.core import provisioner as prov
 from repro.core import replication
 from repro.core import trace
-from repro.core.queueing import BudgetLike, QUEUEING, resolve
+from repro.core.queueing import BudgetLike, resolve
 from repro.core.types import (HardwareSpec, Placement, PlannerConfig,
                               ProvisioningPlan, WorkloadCoefficients,
                               WorkloadSpec, planner_config)
@@ -650,211 +649,6 @@ class HealthMonitor:
 
 
 # ---------------------------------------------------------------------------
-# Persistent plan state: the hot path for incremental edits
-# ---------------------------------------------------------------------------
-
-class PlanState:
-    """A live `VecCluster` mirror of the reconciler's current plan.
-
-    The provisioner-level edits (`resize_workload` & co.) are
-    plan-in/plan-out and rebuild their cluster state per call — exact,
-    oracle-friendly, but O(cluster) each, which at m=1000 puts the
-    controller's own latency (the Sec. 5.5 overhead number) in the tens
-    of seconds.  This mirror keeps the cluster's cached invariants
-    ALIVE across edits so each one costs only the devices it touches:
-    a same-device resize re-runs Alg. 2 against that device alone, a
-    migration scores every device in ONE vectorized `alloc_all`, and a
-    departure is a single `remove_entry`.  Allocation outcomes match
-    the sequential provisioner ops (entry order within a device differs,
-    which the model's symmetric sums make irrelevant) — pinned by
-    `tests/test_controller.py`; emptied devices are additionally reused
-    as migration targets instead of stranding them.
-    """
-
-    def __init__(self, plan: ProvisioningPlan,
-                 profiles: Dict[str, WorkloadCoefficients],
-                 hw: HardwareSpec, budget: BudgetLike = QUEUEING,
-                 backend: str = "numpy",
-                 probes: Optional[prov.ProbeCache] = None,
-                 max_devices: Optional[int] = None,
-                 shadow: Optional[Dict[str, float]] = None):
-        self.hw = hw
-        self.profiles = profiles
-        self.max_devices = max_devices
-        self.hardware = plan.hardware or hw
-        self.probes = probes
-        by_gpu: Dict[int, List[Placement]] = {}
-        for p in plan.placements:
-            by_gpu.setdefault(p.gpu, []).append(p)
-        # row q -> plan gpu id, in add_workload's row order
-        self.row_gpus: List[int] = sorted(by_gpu)
-        # workload name -> row q
-        self.home: Dict[str, int] = {
-            p.workload.name: q for q, g in enumerate(self.row_gpus)
-            for p in by_gpu[g]}
-        self.cl = pmv.VecCluster.from_devices(
-            hw, [[(p.workload, profiles[p.workload.model], p.batch, p.r)
-                  for p in by_gpu[g]] for g in self.row_gpus],
-            budget=budget, backend=backend)
-        self._next_gpu = (max(by_gpu) + 1) if by_gpu else 0
-        # plan gpu ids placement must avoid (health-layer quarantine);
-        # the Reconciler keeps this in sync with its quarantine set
-        self.banned: set = set()
-        # Sec. 4.2 shadow reservations, workload name -> shadow_r.
-        # Shared BY REFERENCE with the owning Reconciler's armed book,
-        # so every placement sweep sees the reservation the moment it
-        # is granted: an activation may push a device to r + shadow_r
-        # but never past 1.0 (tests/test_forecast.py pins this)
-        self.shadow: Dict[str, float] = shadow if shadow is not None \
-            else {}
-
-    def _row_reserved(self, exclude: Optional[str] = None) -> np.ndarray:
-        """Per-row armed shadow reservation: the capacity a monitor-tick
-        activation may claim, which placement must treat as spoken for."""
-        out = np.zeros(self.cl.d)
-        for name, sr in self.shadow.items():
-            if name == exclude:
-                continue
-            q = self.home.get(name)
-            if q is not None:
-                out[q] += sr
-        return out
-
-    def set_budget(self, budget: BudgetLike) -> None:
-        self.cl.set_budget(budget)
-
-    def remove(self, name: str) -> None:
-        q = self.home.pop(name)
-        self.cl.remove_entry(q, self._slot_at(q, name))
-
-    def _slot_at(self, q: int, name: str) -> int:
-        for i, (s, _, _) in enumerate(self.cl.entries[q]):
-            if s.name == name:
-                return i
-        raise KeyError(name)
-
-    def _place(self, spec: WorkloadSpec, c: WorkloadCoefficients,
-               b: int, rl: float) -> None:
-        """Min-interference placement over ALL devices (one vectorized
-        Alg. 2 sweep) with the fresh-device `self_grant` fallback —
-        `add_workload` semantics against the live cluster."""
-        cl = self.cl
-        feasible, rr, rn, r_inter = cl.alloc_all(spec, c, b, rl)
-        if self.banned:
-            mask = np.fromiter((g in self.banned for g in self.row_gpus),
-                               dtype=bool, count=len(self.row_gpus))
-            feasible = feasible & ~mask
-            r_inter = np.where(mask, np.inf, r_inter)
-        if self.shadow:
-            # armed reservations are spoken-for capacity: a row whose
-            # re-solved residents + newcomer + reservations would exceed
-            # r = 1.0 is infeasible for this placement (the activation
-            # headroom must survive every edit)
-            resv = self._row_reserved(exclude=spec.name)
-            if resv.any():
-                load = (rr * cl.mask[:cl.d]).sum(axis=1) + rn + resv
-                over = load > 1.0 + 1e-9
-                if over.any():
-                    feasible = feasible & ~over
-                    r_inter = np.where(over, np.inf, r_inter)
-        if self.max_devices is not None:
-            used = sum(1 for q in range(cl.d) if cl.entries[q])
-            if used >= self.max_devices:
-                # cap reached: an EMPTY row is one more device in use
-                # the moment anything lands on it, so mask empty rows
-                # from the sweep along with refusing the fresh fallback
-                empty = np.fromiter((not cl.entries[q]
-                                     for q in range(cl.d)),
-                                    dtype=bool, count=cl.d)
-                if empty.any():
-                    feasible = feasible & ~empty
-                    r_inter = np.where(empty, np.inf, r_inter)
-        row = prov._argmin_inter(r_inter) if feasible.any() else -1
-        if row == -1:
-            if self.max_devices is not None:
-                prov._check_device_cap(
-                    sum(1 for q in range(cl.d) if cl.entries[q]),
-                    self.max_devices, spec.name, self.hw)
-            row = cl.add_device()
-            self.row_gpus.append(self._next_gpu)
-            self._next_gpu += 1
-            cl.add_entry(row, spec, c, b,
-                         prov.self_grant(spec, c, b, rl, self.hw,
-                                         budget=cl.bm))
-        else:
-            cl.set_row_r(row, rr[row])
-            cl.add_entry(row, spec, c, b, float(rn[row]))
-        self.home[spec.name] = row
-
-    def _theorem1(self, spec: WorkloadSpec, c: WorkloadCoefficients,
-                  batch: str) -> tuple:
-        """(b_appr, r_lower) through the shared probe cache when one is
-        attached — repeat edits to a (spec, budget) pair skip the
-        joint-batch scan entirely."""
-        if self.probes is not None:
-            return self.probes.theorem1(spec, c, self.hw, self.cl.bm, batch)
-        b = prov.appropriate_batch(spec, c, self.hw, budget=self.cl.bm,
-                                   batch=batch)
-        rl = prov.resource_lower_bound(spec, c, self.hw, b,
-                                       budget=self.cl.bm)
-        return b, rl
-
-    def add(self, spec: WorkloadSpec, *, batch: str = "joint",
-            pin: Optional[tuple] = None) -> None:
-        """``pin=(batch, r_floor)`` bypasses Theorem 1 — the health
-        layer's capacity-preserving migration (`prov.add_workload`
-        semantics)."""
-        c = self.profiles[spec.model]
-        if pin is not None:
-            b, rl = int(pin[0]), float(pin[1])
-        else:
-            b, rl = self._theorem1(spec, c, batch)
-        self._place(spec, c, b, rl)
-
-    def resize(self, spec: WorkloadSpec, *, batch: str = "joint") -> None:
-        """Theorem 1 at the new rate, same-device Alg. 2 re-run first,
-        vectorized migration fallback (provisioner.resize_workload
-        semantics, O(devices touched))."""
-        c = self.profiles[spec.model]
-        b, rl = self._theorem1(spec, c, batch)
-        cl = self.cl
-        q = self.home.pop(spec.name)
-        cl.remove_entry(q, self._slot_at(q, spec.name))
-        if self.row_gpus[q] in self.banned:
-            # quarantined home device: no same-device fast path — the
-            # resize IS the eviction (min-interference move elsewhere)
-            self._place(spec, c, b, rl)
-            return
-        residents = [(s, cc, bb, float(cl.r[q, i]))
-                     for i, (s, cc, bb) in enumerate(cl.entries[q])]
-        r_a = pmv.alloc_gpus_vec(residents, spec, c, b, rl, self.hw,
-                                 budget=cl.bm)
-        if r_a is not None and self.shadow:
-            resv_q = math.fsum(
-                sr for n2, sr in self.shadow.items()
-                if n2 != spec.name and self.home.get(n2) == q)
-            if math.fsum(r_a) + resv_q > 1.0 + 1e-9:
-                r_a = None           # the reservation holds: migrate
-        if r_a is not None:
-            cl.set_row_r(q, np.array(r_a[:-1]))
-            cl.add_entry(q, spec, c, b, r_a[-1])
-            self.home[spec.name] = q
-        else:
-            self._place(spec, c, b, rl)
-
-    def to_plan(self) -> ProvisioningPlan:
-        plan = ProvisioningPlan(hardware=self.hardware)
-        cl = self.cl
-        for q in range(cl.d):
-            for i, (s, _, b) in enumerate(cl.entries[q]):
-                plan.placements.append(Placement(
-                    workload=s, gpu=self.row_gpus[q],
-                    r=float(cl.r[q, i]), batch=b))
-        plan.n_gpus = sum(1 for q in range(cl.d) if cl.entries[q])
-        return plan
-
-
-# ---------------------------------------------------------------------------
 # Drift reconciliation over incremental plan edits
 # ---------------------------------------------------------------------------
 
@@ -925,7 +719,7 @@ class Reconciler:
         # engine="vec": lazily-built persistent VecCluster mirror (the
         # O(devices-touched) hot path); engine="scalar": each edit goes
         # through the plan-in/plan-out provisioner ops (the oracle)
-        self._state: Optional[PlanState] = None
+        self._state: Optional[prov.PlanState] = None
         self._state_bm = self.bm
         # targets are keyed by BASE workload name: a replica group is
         # reconciled as ONE workload whose target spec carries the full
@@ -1131,12 +925,12 @@ class Reconciler:
         if self.engine != "vec":
             return
         if self._state is None:
-            self._state = PlanState(self.plan, self.profiles, self.hw,
-                                    budget=self.bm,
-                                    backend=self.planner.backend,
-                                    probes=self.probes,
-                                    max_devices=self.max_devices,
-                                    shadow=self.armed)
+            self._state = prov.PlanState(self.plan, self.profiles,
+                                         self.hw, budget=self.bm,
+                                         backend=self.planner.backend,
+                                         probes=self.probes,
+                                         max_devices=self.max_devices,
+                                         shadow=self.armed)
             self._state_bm = self.bm
             self._state.banned = set(self.quarantined)
         elif self.bm != self._state_bm:

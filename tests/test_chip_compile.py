@@ -58,11 +58,6 @@ def _compile(fn, *args):
     return compiled
 
 
-def _coeff_tuple(sharding, shape):
-    from repro.core import perf_model_vec as pmv
-    return tuple(_sds(sharding, shape) for _ in pmv.COEFF_FIELDS)
-
-
 def _state_width():
     from repro.core import perf_model_jax as pmj
     return len(pmj.SLOT_FIELDS) * CAP_N + len(pmj.ROW_FIELDS)
@@ -89,19 +84,3 @@ def test_latency_tables_compile_for_v5e(one_chip, n_co):
     from repro.serving import physics_jax
     _compile(physics_jax._tables_jit, V5E, n_co,
              *(_sds(one_chip, (ROWS, n_co)) for _ in range(8)))
-
-
-def test_budget_bisection_compiles_for_v5e(one_chip):
-    from repro.core import perf_model_jax as pmj
-    _compile(pmj._budget_bisect_jit,
-             *(_sds(one_chip, (CAP_D,)) for _ in range(3)),
-             *(_sds(one_chip, ()) for _ in range(3)))
-
-
-def test_device_batch_eval_compiles_for_v5e(one_chip):
-    from repro.core import perf_model_jax as pmj
-    from repro.core.types import V5E
-    dn = (CAP_D, CAP_N)
-    _compile(pmj._eval_jit, _coeff_tuple(one_chip, dn),
-             _sds(one_chip, dn), _sds(one_chip, dn),
-             _sds(one_chip, dn, "bool"), V5E)

@@ -250,9 +250,8 @@ def test_planstate_matches_sequential_provisioner_ops(ctx12):
     irrelevant to the model's symmetric sums — and PlanState may reuse
     an emptied device where the ops would open a fresh one)."""
     import dataclasses
-    from repro.serving.controller import PlanState
     ctx, plan = ctx12
-    state = PlanState(plan, ctx.profiles, ctx.hw)
+    state = prov.PlanState(plan, ctx.profiles, ctx.hw)
     seq = plan
     specs = {p.workload.name: p.workload for p in plan.placements}
     edits = [("resize", "W5", 1.3), ("remove", "W2", None),

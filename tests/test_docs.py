@@ -54,6 +54,7 @@ CLASSES = {
     "ProbeCache": "src/repro/core/provisioner.py",
     "InfeasibleError": "src/repro/core/provisioner.py",
     "DeviceCapError": "src/repro/core/provisioner.py",
+    "PlanState": "src/repro/core/provisioner.py",
     "CoeffArrays": "src/repro/core/perf_model_vec.py",
     "VecCluster": "src/repro/core/perf_model_vec.py",
     "BudgetModel": "src/repro/core/queueing.py",
@@ -66,7 +67,6 @@ CLASSES = {
     "ControllerConfig": "src/repro/serving/controller.py",
     "Reconciler": "src/repro/serving/controller.py",
     "Controller": "src/repro/serving/controller.py",
-    "PlanState": "src/repro/serving/controller.py",
     "PlanEdit": "src/repro/serving/controller.py",
     "Telemetry": "src/repro/serving/telemetry.py",
     "RingBuffer": "src/repro/serving/telemetry.py",

@@ -31,7 +31,7 @@ except ImportError:      # bare env: property tests skip, unit tests run
 from repro.core import provisioner as prov
 from repro.core.experiments import fitted_context
 from repro.serving.controller import (ArrivalEstimator, ControllerConfig,
-                                      PlanState, Reconciler)
+                                      Reconciler)
 from repro.serving.workload import twelve_workloads
 
 WINDOW_MS = 1000.0
@@ -326,7 +326,7 @@ def test_failed_edit_restores_plan_mirror_and_armed(ctx12, monkeypatch):
     armed_dict = rec.armed
 
     calls = {"n": 0}
-    real_resize, real_remove = PlanState.resize, PlanState.remove
+    real_resize, real_remove = prov.PlanState.resize, prov.PlanState.remove
 
     # fail whichever op the edit takes first — a same-membership edit
     # goes through resize, a re-split through remove; both fire AFTER
@@ -339,13 +339,13 @@ def test_failed_edit_restores_plan_mirror_and_armed(ctx12, monkeypatch):
         calls["n"] += 1
         raise prov.DeviceCapError(name)
 
-    monkeypatch.setattr(PlanState, "resize", failing_resize)
-    monkeypatch.setattr(PlanState, "remove", failing_remove)
+    monkeypatch.setattr(prov.PlanState, "resize", failing_resize)
+    monkeypatch.setattr(prov.PlanState, "remove", failing_remove)
     changed = rec._forecast_act(99.0, base, est,
                                 est.rate_rps * 1.2,
                                 backlog=0.0)
-    monkeypatch.setattr(PlanState, "resize", real_resize)
-    monkeypatch.setattr(PlanState, "remove", real_remove)
+    monkeypatch.setattr(prov.PlanState, "resize", real_resize)
+    monkeypatch.setattr(prov.PlanState, "remove", real_remove)
 
     assert calls["n"] >= 1, "injection never reached the edit path"
     # the pre-size failed; re-arming the unchanged group is a no-op, so

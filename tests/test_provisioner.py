@@ -376,3 +376,89 @@ def test_joint_batch_plan_never_worse_at_m100():
     oracle = prov.provision(specs, ctx.profiles, ctx.hw, batch="joint",
                             engine="scalar")
     assert _plan_key(joint) == _plan_key(oracle)
+
+
+# ---------------------------------------------------------------------------
+# One online placement decision (`_choose_row`) behind `add_workload`'s vec
+# engine and `PlanState`: each constraint binds on an m=30 standing plan
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def standing30():
+    from repro.core.experiments import fitted_context
+    from repro.serving.workload import synthetic_workloads
+    ctx = fitted_context()
+    return ctx, prov.provision(synthetic_workloads(30, seed=3),
+                               ctx.profiles, ctx.hw)
+
+
+def _rows(plan):
+    return [(p.workload.name, p.gpu, round(p.r, 9), p.batch)
+            for p in plan.placements]
+
+
+def _landed(plan, name):
+    return next((p.gpu, p.r) for p in plan.placements
+                if p.workload.name == name)
+
+
+@pytest.mark.parametrize("case", ["ban", "reserve", "cap", "pin"])
+def test_online_placement_decision_engines_and_planstate_agree(standing30,
+                                                               case):
+    """`add_workload` with the vec and the scalar engine gives the same
+    ordered plan rows (or the same error) under an exclusion, an armed
+    reservation, a binding device cap and a pin; `PlanState.add` under
+    the same constraint lands the newcomer on the same device with the
+    same r."""
+    from repro.core.types import PlannerConfig
+    ctx, plan = standing30
+    new = WorkloadSpec("new", "rwkv6-1.6b", 156.2, 20.8)
+    g0 = _landed(prov.add_workload(plan, new, ctx.profiles, ctx.hw),
+                 new.name)[0]
+    assert g0 in {p.gpu for p in plan.placements}   # lands on a resident
+    kw, pin = {}, None
+    state = prov.PlanState(plan, ctx.profiles, ctx.hw)
+    if case == "ban":
+        kw["exclude_gpus"] = frozenset({g0})
+        state.banned = {g0}
+    elif case == "reserve":
+        free = 1.0 - plan.total_allocated(g0)
+        kw["reserved"] = {g0: free}
+        holder = next(p.workload.name for p in plan.placements
+                      if p.gpu == g0)
+        state.shadow[holder] = free
+    elif case == "cap":
+        new = WorkloadSpec("new", "qwen2-vl-7b", 156.2, 41.6)
+        uncapped = prov.add_workload(plan, new, ctx.profiles, ctx.hw)
+        assert _landed(uncapped, new.name)[0] == max(plan.by_gpu()) + 1
+        kw["max_devices"] = state.max_devices = plan.n_gpus
+    else:
+        pin = kw["pin"] = (2, 0.3)
+
+    got = {}
+    for engine in ("vec", "scalar"):
+        try:
+            got[engine] = _rows(prov.add_workload(
+                plan, new, ctx.profiles, ctx.hw,
+                config=PlannerConfig(engine=engine), **kw))
+        except prov.InfeasibleError as e:
+            got[engine] = type(e)
+    assert got["vec"] == got["scalar"]
+    try:
+        state.add(new, batch="eq17", pin=pin)
+        live = _landed(state.to_plan(), new.name)
+    except prov.InfeasibleError as e:
+        live = type(e)
+
+    if case == "cap":
+        assert got["vec"] is prov.DeviceCapError
+        assert live is prov.DeviceCapError
+        return
+    want = next((g, r) for name, g, r, _ in got["vec"] if name == new.name)
+    assert (live[0], round(live[1], 9)) == want
+    if case == "pin":
+        assert next(b for name, _, _, b in got["vec"]
+                    if name == new.name) == pin[0]
+        assert want[1] >= pin[1] - 1e-12
+    else:
+        assert want[0] != g0                  # the constraint binds
