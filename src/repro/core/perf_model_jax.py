@@ -9,9 +9,12 @@ path (Alg. 1, `add_workload`, the controller's `PlanState`):
                               kept on the device (see Residency)
   * ``_scatter_rows_jit``     dirty rows written into that copy
   * ``_alloc_all_jit``        Algorithm 2 against every open device as
-                              ONE `lax.while_loop`; returns grant counts
-  * ``alloc_all_jax``         the dispatch target: sync, launch, fetch,
-                              and the host replay of the grants
+                              ONE `lax.while_loop`; returns the grant
+                              counts and verdicts packed as one int32
+                              (cap_d, cap_n + 2) array
+  * ``alloc_all_jax``         the dispatch target: sync, launch, one
+                              copy of that array to the host, and the
+                              host replay of the grants
 
 Layout contract: shapes are the VecCluster capacities (powers of two),
 NOT the live device count d — d arrives as a traced scalar and
@@ -166,10 +169,13 @@ def _alloc_all_jit(hw: HardwareSpec, state, new):
     accumulation) become masked row sums.  Both stay ulps away from
     the oracle's values, far inside the 1e-9 decision epsilons.
 
-    Returns ``(feasible, g_res, g_new, iters)``: the verdict per
-    device, how many ``+r_unit`` grants each resident and the newcomer
-    took, and the number of loop iterations (the numpy loop's count).
-    The caller replays those grants on the host (`alloc_all_jax`).
+    Returns ``(packed, iters)``.  ``packed`` is one int32 array of
+    shape (cap_d, cap_n + 2), so that the host fetches the answer in
+    one copy: columns ``0..cap_n-1`` hold how many ``+r_unit`` grants
+    each resident took, column ``cap_n`` the newcomer's grants and
+    column ``cap_n + 1`` the device's verdict as 0/1.  ``iters`` is the
+    number of loop iterations (the numpy loop's count), a scalar of its
+    own.  The caller replays the grants on the host (`alloc_all_jax`).
     """
     f = _unpack(state)
     ca = tuple(f[name] for name in pmv.COEFF_FIELDS)
@@ -272,7 +278,9 @@ def _alloc_all_jit(hw: HardwareSpec, state, new):
             jnp.ones(cap_d, dtype=bool), jnp.int32(0))
     (_, _, g_res, g_new, *_, feasible, iters) = lax.while_loop(cond, body,
                                                                init)
-    return feasible, g_res, g_new, iters
+    packed = jnp.concatenate(
+        [g_res, g_new[:, None], feasible[:, None].astype(jnp.int32)], axis=1)
+    return packed, iters
 
 
 def alloc_all_jax(cl: "pmv.VecCluster", spec: WorkloadSpec,
@@ -291,6 +299,8 @@ def alloc_all_jax(cl: "pmv.VecCluster", spec: WorkloadSpec,
 
     The cluster reaches the device through `sync` (its dirty rows, or
     the whole state), the number of rows copied into ``cl.rows_sent``.
+    The answer comes back in one device-to-host copy of the kernel's
+    packed array, split here into grants and verdicts.
     While a profiler trace records, the loop's iteration count is
     fetched too, into ``cl.iters``, its copy started before the fetch so
     that it overlaps it; otherwise it stays on the device.
@@ -305,14 +315,16 @@ def alloc_all_jax(cl: "pmv.VecCluster", spec: WorkloadSpec,
         cl.rows_sent = sync(cl)
         new = np.array(_coeff_scalars(coeffs)
                        + (batch, r_lower, budget_new, d), dtype=np.float64)
-        out = _alloc_all_jit(hw, cl.mirror, new)
+        packed, iters = _alloc_all_jit(hw, cl.mirror, new)
     counting = trace.active()
     if counting:
-        out[3].copy_to_host_async()
+        iters.copy_to_host_async()
     with trace.span("alloc_all.fetch"):
-        feasible, g_res, g_new = (np.asarray(a)[:d] for a in out[:3])
+        packed = np.asarray(packed)[:d]
+        g_res, g_new = packed[:, :-2], packed[:, -2]
+        feasible = packed[:, -1] != 0
     if counting:
-        cl.iters = int(out[3])
+        cl.iters = int(iters)
     with trace.span("alloc_all.replay"):
         r0 = cl.r[:d]
         rr = r0.copy()

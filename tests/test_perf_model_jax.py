@@ -256,6 +256,53 @@ def test_steady_provision_sends_one_row_per_call(monkeypatch, tmp_path):
     assert steady > len(sent) / 2
 
 
+class _CountedReads:
+    """A device array whose reads by the host are logged in ``log`` as
+    ``(output index, method)``."""
+
+    def __init__(self, a, i, log):
+        self._a, self._i, self._log = a, i, log
+
+    def __array__(self, *args, **kwargs):
+        self._log.append((self._i, "__array__"))
+        return self._a.__array__(*args, **kwargs)
+
+    def __int__(self):
+        self._log.append((self._i, "__int__"))
+        return int(self._a)
+
+    def copy_to_host_async(self):
+        self._log.append((self._i, "copy_to_host_async"))
+        self._a.copy_to_host_async()
+
+
+def test_each_grant_loop_call_reads_one_array_off_a_trace(monkeypatch):
+    """Off a trace, each jax grant-loop call of a provision copies ONE
+    array to the host, the packed answer, and leaves the iteration count
+    on the device; the plan is the numpy backend's."""
+    from repro.core import perf_model_jax as pmj
+    from repro.core import trace
+    from repro.core.experiments import fitted_context
+    from repro.serving.workload import synthetic_workloads
+    real = pmj._alloc_all_jit
+    calls = []
+
+    def counted(*args, **kwargs):
+        calls.append([])
+        return tuple(_CountedReads(a, i, calls[-1])
+                     for i, a in enumerate(real(*args, **kwargs)))
+    monkeypatch.setattr(pmj, "_alloc_all_jit", counted)
+    ctx = fitted_context("tpu-v5e")
+    specs = synthetic_workloads(20, seed=0)
+    assert not trace.active()
+    jx = prov.provision(specs, ctx.profiles, ctx.hw,
+                        config=PlannerConfig(backend="jax"))
+    ref = prov.provision(specs, ctx.profiles, ctx.hw)
+    assert len(calls) >= 20
+    assert all(c == [(0, "__array__")] for c in calls), calls
+    assert plan_key(jx) == plan_key(ref)
+
+
 def test_numpy_planner_leaves_jax_module_and_x64_alone():
     """A numpy-backend provision, in a fresh process, imports no jax
     twin and leaves float64 off: the mirror lives on the jax side."""
